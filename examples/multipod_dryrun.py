@@ -27,7 +27,8 @@ def main():
     args = ap.parse_args()
 
     with tempfile.TemporaryDirectory() as td:
-        env = dict(os.environ, PYTHONPATH=str(ROOT / "src"))
+        env = dict(os.environ, PYTHONPATH=str(ROOT / "src"),
+                   JAX_PLATFORMS="cpu")  # placeholder devices only
         cmd = [sys.executable, "-m", "repro.launch.dryrun",
                "--arch", args.arch, "--shape", args.shape,
                "--mesh", args.mesh, "--out", td, "--tag", "x"]

@@ -149,11 +149,31 @@ _JIT_SCATTER_Q = jax.jit(_scatter_quant, static_argnums=(4,),
 _KERNEL_JITS: Dict[tuple, object] = {}
 
 
+def kernel_gather_ok(backend: str, d: int, fast_dtype) -> bool:
+    """Whether the compiled Pallas row gather can serve a fast tier of
+    ``fast_dtype`` rows of width ``d`` on ``backend``: a TPU, D a multiple
+    of 128 (the lane width) and 32-bit rows.  The TPU compiler refuses a
+    one-row DMA of 1-byte rows (they pack four to a sublane), so a
+    quantized fast tier takes the XLA gather and quantizer on the TPU
+    (docs/architecture.md, "The quantized fast tier")."""
+    return (backend == "tpu" and d % 128 == 0
+            and np.dtype(fast_dtype).itemsize == 4)
+
+
+def _n_unique(iv):
+    """Unique-row count of a packed ``(2, bucket)`` gather operand: the
+    unique->request inverse (row 1) is onto ``range(u)`` and its padding
+    is 0, so ``u = max(inverse) + 1``."""
+    return jnp.max(iv[1]) + 1
+
+
 def _kernel_gathers(quantized: bool = False, interpret: bool = False):
     """Pallas row-gather variants, built lazily (TPU backend, or any
-    backend under ``interpret=True``).  ``quantized=True`` returns the
-    fused dequantizing pair (int8/fp8 row + per-row scale DMA'd HBM->VMEM,
-    dequantized in-kernel) with the overflow where-select folded in."""
+    backend under ``interpret=True``).  The fp32 pair copies only the
+    ``u`` unique rows of the bucket; ``quantized=True`` returns the
+    dequantizing pair (interpret mode only, see :func:`kernel_gather_ok`).
+    Both fold the overflow where-select and the unique->request expansion
+    into the same program."""
     key = ("gq" if quantized else "g", interpret)
     if key not in _KERNEL_JITS:
         from repro.kernels import embedding_gather as eg
@@ -170,11 +190,12 @@ def _kernel_gathers(quantized: bool = False, interpret: bool = False):
                                            interpret=_i))[iv[1]]
         else:
             def g(buf, iv, _i=interpret):
-                return eg.gather_rows(buf, iv[0], interpret=_i)[iv[1]]
+                return eg.gather_rows(buf, iv[0], _n_unique(iv),
+                                      interpret=_i)[iv[1]]
 
             def gov(buf, iv, ov, hr, _i=interpret):
                 return jnp.where(ov[:, None], hr,
-                                 eg.gather_rows(buf, iv[0],
+                                 eg.gather_rows(buf, iv[0], _n_unique(iv),
                                                 interpret=_i))[iv[1]]
         _KERNEL_JITS[key] = (jax.jit(g), jax.jit(gov))
     return _KERNEL_JITS[key]
@@ -280,12 +301,13 @@ class TieredEmbeddingStore:
         without ``quantize=True`` is an error.
 
         ``use_kernel``: route the device gather (and, under quantize, the
-        admit-side quantizer) through the fused Pallas kernels.  Default
-        auto: TPU backend with a lane-aligned D.  An *explicit*
-        ``use_kernel=True`` is validated, never silently downgraded: off
-        the TPU backend it needs ``kernel_interpret=True`` (the Pallas
-        interpreter — the CPU test lane), and D must be a multiple of 128
-        on the compiled path.
+        admit-side quantizer) through the Pallas kernels.  Default auto:
+        :func:`kernel_gather_ok` — a TPU, D % 128 == 0 and 32-bit fast-tier
+        rows.  An *explicit* ``use_kernel=True`` is validated, never
+        silently downgraded: off the TPU backend it needs
+        ``kernel_interpret=True`` (the Pallas interpreter — the CPU test
+        lane), and on the compiled path D must be a multiple of 128 and
+        the rows 32-bit (so not ``quantize=True``).
 
         ``warmup_batch``: eagerly compile the jitted scatter/gather for
         every power-of-two shape bucket a batch of up to this many ids can
@@ -328,25 +350,32 @@ class TieredEmbeddingStore:
         self.stats = TierStats()
         self._staged: List[Tuple[np.ndarray, np.ndarray, np.ndarray]] = []
         self.kernel_interpret = bool(kernel_interpret)
-        on_tpu = jax.default_backend() == "tpu"
+        backend = jax.default_backend()
+        fast_dtype = self.buffer.dtype
         if use_kernel is None:
             # Auto mode may downgrade: the kernel path only engages when
             # the backend can actually compile it for this table shape.
-            use_kernel = on_tpu and d % 128 == 0
-        elif use_kernel:
+            use_kernel = kernel_gather_ok(backend, d, fast_dtype)
+        elif use_kernel and not self.kernel_interpret:
             # An explicit request is a contract — validate, never
             # silently drop (the old ``and not quantize`` downgrade hid
             # exactly this class of misconfiguration).
-            if not on_tpu and not self.kernel_interpret:
+            if backend != "tpu":
                 raise ValueError(
                     "use_kernel=True requires the TPU backend; pass "
                     "kernel_interpret=True to run the Pallas kernels in "
                     "interpret mode (the CPU test lane)")
-            if not self.kernel_interpret and d % 128:
+            if d % 128:
                 raise ValueError(
                     f"use_kernel=True requires D % 128 == 0 (got D={d}): "
                     "the compiled kernels stream rows through the 128-lane "
                     "layout — pad the table or pass kernel_interpret=True")
+            if not kernel_gather_ok(backend, d, fast_dtype):
+                raise ValueError(
+                    f"use_kernel=True on the TPU requires 32-bit fast-tier "
+                    f"rows (got {np.dtype(fast_dtype)}): a one-row DMA of "
+                    "quantized rows does not compile, so quantized stores "
+                    "take the XLA gather there")
         self.use_kernel = bool(use_kernel)
         if self.use_kernel:
             self._gather_inv, self._gather_ov = _kernel_gathers(
@@ -468,6 +497,14 @@ class TieredEmbeddingStore:
                                            jnp.asarray(r0))
             b <<= 1
         jax.block_until_ready(self.buffer)
+
+    def gather_program_text(self, n_ids: int) -> str:
+        """Compiled HLO text of this store's lookup gather for a batch of
+        ``n_ids`` ids (its shape bucket): the program every such batch
+        runs.  A ``tpu_custom_call`` in it marks the Pallas kernel path."""
+        iv = jnp.zeros((2, _bucket(n_ids)), jnp.int32)
+        args = (self.buffer, self.scales) if self.quantize else (self.buffer,)
+        return self._gather_inv.lower(*args, iv).compile().as_text()
 
     # ---------------- slot allocation / eviction ----------------
 

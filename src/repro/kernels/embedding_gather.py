@@ -1,25 +1,33 @@
-"""Fused embedding gather + sum-pool Pallas TPU kernels, fp32 and quantized.
+"""Embedding row-gather, pooled-gather and quantize Pallas TPU kernels.
 
 This is the paper's hot spot: multi-hot lookups into large embedding tables
-(TorchRec's fused kernels on GPU).  TPU-native formulation: the multi-hot
-index matrix is *scalar-prefetched* so it can drive ``BlockSpec.index_map``
-— each grid step DMAs exactly one needed table row HBM->VMEM (no
-gather-scatter in registers, rows stream through the MXU-aligned 128-lane
-layout) and accumulates the pool sum in the revisited output block.
+(TorchRec's fused kernels on GPU).  TPU-native formulation: the table stays
+in HBM (``memory_space=ANY``) and every wanted row is copied by its own
+DMA, addressed by an index read from SMEM.  Two layout rules of the TPU
+compiler shape the kernels:
 
-Grid: (batch, pooling) with the pooling axis innermost — the output block
-(1, D) stays resident in VMEM across the whole pooling loop and is written
-back once (TPU grids are sequential, revisited blocks are kept live).
+* A block's last two dimensions must be multiples of the dtype's tile
+  (8 x 128 for 32-bit values), so no block is one row high.  Rows are
+  copied one at a time by ``make_async_copy`` instead, into an HBM output
+  (``gather_rows``) or an aligned VMEM block (the pooled gathers).
+* A one-row DMA is accepted only for 32-bit rows: 1- and 2-byte dtypes
+  pack several rows per sublane, and the compiler refuses a slice of one
+  ("aligned to tiling").  So the compiled path takes float32 / int32
+  rows; the quantized (1-byte) variants run in interpret mode only, and
+  the tiered store routes quantized rows to the XLA gather on the TPU
+  (rule in ``docs/architecture.md``, "The quantized fast tier").
+
+The index vector is never one scalar-prefetch operand: SMEM holds 1 MiB,
+and a full-width bucket (524,288 ids) needs 2 MiB.  Indices are instead
+blocked ``(n_blocks, 1, K)`` and each grid step reads its ``(1, K)`` block
+into SMEM.  At most ``_DMA_WINDOW`` row copies are in flight at a time.
 
 The ``*_dequant`` variants serve the quantized fast tier (SDM's
-capacity/precision trade): the table holds int8 or fp8 rows with one fp32
-scale per row, and dequantization happens *in kernel* — each grid step DMAs
-the 1-byte-per-element row plus its (1, 1) scale and multiplies in VMEM, so
-the HBM traffic per gathered row is ``D + 4`` bytes instead of ``4 * D``.
-``quantize_rows`` is the matching populate-side kernel: per-row absmax ->
-scale -> round/clip device-side, so admits never round-trip through host
-NumPy.  Row formats (``ROW_FORMATS``): ``int8`` (symmetric, +-127) and
-``fp8`` (``float8_e4m3fn``, +-448).
+capacity/precision trade): int8 or fp8 rows with one fp32 scale per row,
+dequantized in VMEM.  ``quantize_rows`` is the populate-side kernel: per-row
+absmax -> scale -> round/clip over 32-row tiles (the int8 sublane tile).
+Row formats (``ROW_FORMATS``): ``int8`` (symmetric, +-127) and ``fp8``
+(``float8_e4m3fn``, +-448).
 """
 from __future__ import annotations
 
@@ -27,6 +35,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -37,85 +47,162 @@ ROW_FORMATS = {
     "fp8": (jnp.float8_e4m3fn, 448.0),
 }
 
+_ROW_BLOCK = 1024  # gather_rows: ids per grid step (one SMEM index block)
+_POOL_BLOCK = 8  # pooled gathers: queries per grid step (f32 sublane tile)
+_QUANT_TILE = 32  # quantize_rows: rows per grid step (int8 sublane tile)
+_DMA_WINDOW = 64  # most row copies in flight at once
 
-def _check_lane_width(d: int, interpret: bool, fn: str):
-    """The compiled TPU path streams rows through the 128-lane VREG
-    layout; a ragged last lane-group silently corrupts the DMA tiling, so
-    fail loudly instead (the interpret path has no such constraint)."""
-    if not interpret and d % 128:
+
+def _check_compiled_rows(table, interpret: bool, fn: str):
+    """The compiled TPU path copies rows one DMA each into 128-lane tiles:
+    D must be a multiple of 128 and rows must be 32-bit (narrower dtypes
+    pack rows per sublane, and a one-row slice of them is refused).  Fail
+    loudly instead; the interpret path has no such constraint."""
+    if interpret:
+        return
+    d = table.shape[-1]
+    if d % 128:
         raise ValueError(
             f"{fn}: embedding dim D={d} must be a multiple of 128 (TPU "
             "lane width) on the compiled path — pad the table to a "
             "multiple of 128 or pass interpret=True")
+    if np.dtype(table.dtype).itemsize != 4:
+        raise ValueError(
+            f"{fn}: the compiled path copies one row per DMA, which the "
+            f"TPU accepts only for 32-bit rows (got {table.dtype}); pass "
+            "interpret=True or gather these rows with XLA")
 
 
-def _gather_pool_kernel(idx_ref, table_ref, out_ref):
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += table_ref[...].astype(out_ref.dtype)
-
-
-def _gather_rows_kernel(idx_ref, table_ref, out_ref):
-    out_ref[...] = table_ref[...]
+def _index_blocks(idx: jax.Array, k: int) -> jax.Array:
+    """Flat ids -> ``(n_blocks, 1, k)`` int32, zero-padded: one ``(1, k)``
+    SMEM block per grid step (a block equal to the array's last two dims
+    is tile-legal for any k)."""
+    idx = idx.astype(jnp.int32).reshape(-1)
+    nb = max(1, pl.cdiv(idx.size, k))
+    return jnp.pad(idx, (0, nb * k - idx.size)).reshape(nb, 1, k)
 
 
-def gather_rows(table: jax.Array, idx: jax.Array, *,
+def _smem_block(k: int):
+    return pl.BlockSpec((None, 1, k), lambda i, *_: (i, 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+def _copy_rows(n, copy):
+    """Run ``copy(j)`` for j in [0, n) with at most ``_DMA_WINDOW`` copies
+    in flight.  All copies share one semaphore and one size, so each wait
+    retires one copy's worth; after the drain every copy has landed."""
+    def issue(j, carry):
+        @pl.when(j >= _DMA_WINDOW)
+        def _():
+            copy(j - _DMA_WINDOW).wait()
+
+        copy(j).start()
+        return carry
+
+    lax.fori_loop(0, n, issue, 0)
+
+    def drain(j, carry):
+        copy(j).wait()
+        return carry
+
+    lax.fori_loop(jnp.maximum(n - _DMA_WINDOW, 0), n, drain, 0)
+
+
+def _gather_rows_kernel(n_ref, idx_ref, table_hbm, out_hbm, sem):
+    base = pl.program_id(0) * _ROW_BLOCK
+    n = jnp.clip(n_ref[0] - base, 0, _ROW_BLOCK)
+
+    def copy(j):
+        return pltpu.make_async_copy(
+            table_hbm.at[pl.ds(idx_ref[0, j], 1)],
+            out_hbm.at[pl.ds(base + j, 1)], sem)
+
+    _copy_rows(n, copy)
+
+
+def gather_rows(table: jax.Array, idx: jax.Array, n_valid=None, *,
                 interpret: bool = False) -> jax.Array:
-    """table: (N, D); idx: (M,) -> (M, D) = table[idx], no pooling.
+    """table: (N, D); idx: (M,) -> (M, D) with row m = table[idx[m]] for
+    m < ``n_valid`` (default M), no pooling.
 
-    The un-pooled gather the tiered serving buffer uses: the flat slot-index
-    vector is scalar-prefetched so ``BlockSpec.index_map`` DMAs exactly the
-    needed buffer row HBM->VMEM per grid step (same streaming layout as
-    ``gather_pool``, minus the accumulation).  D must be a multiple of 128
-    (lane width) for the non-interpret path (checked).
+    The un-pooled gather the tiered serving buffer uses.  Each row is one
+    HBM->HBM DMA; rows at and past ``n_valid`` (a traced scalar is fine)
+    are not copied and hold unspecified values, so a caller that pads
+    ``idx`` to a shape bucket pays only for the rows it uses.  The
+    compiled path needs 32-bit rows and D % 128 == 0 (checked).
     """
     (M,) = idx.shape
     N, D = table.shape
-    _check_lane_width(D, interpret, "gather_rows")
+    _check_compiled_rows(table, interpret, "gather_rows")
+    blocks = _index_blocks(idx, _ROW_BLOCK)
+    n = jnp.minimum(M if n_valid is None else n_valid, M)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(M,),
-        in_specs=[
-            pl.BlockSpec((1, D), lambda m, idx_ref: (idx_ref[m], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, D), lambda m, idx_ref: (m, 0)),
+        grid=(blocks.shape[0],),
+        in_specs=[_smem_block(_ROW_BLOCK),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
     )
     return pl.pallas_call(
         _gather_rows_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, D), table.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), table)
+    )(jnp.reshape(n, (1,)).astype(jnp.int32), blocks, table)
+
+
+def _pooled_blocks(idx: jax.Array) -> jax.Array:
+    """(B, P) ids -> (n_blocks, 1, P * _POOL_BLOCK), p-major inside each
+    block of ``_POOL_BLOCK`` queries (entry j = p * 8 + b)."""
+    B, P = idx.shape
+    nb = max(1, pl.cdiv(B, _POOL_BLOCK))
+    idx = jnp.pad(idx.astype(jnp.int32), ((0, nb * _POOL_BLOCK - B), (0, 0)))
+    idx = idx.reshape(nb, _POOL_BLOCK, P).transpose(0, 2, 1)
+    return idx.reshape(nb, 1, P * _POOL_BLOCK)
+
+
+def _copy_pool_rows(idx_ref, table_hbm, rows, sem, n):
+    """DMA the block's ``n = P * 8`` rows into ``rows`` (P, 8, D)."""
+    def copy(j):
+        return pltpu.make_async_copy(
+            table_hbm.at[pl.ds(idx_ref[0, j], 1)],
+            rows.at[j // _POOL_BLOCK, pl.ds(j % _POOL_BLOCK, 1)], sem)
+
+    _copy_rows(n, copy)
+
+
+def _gather_pool_kernel(idx_ref, table_hbm, out_ref, rows, sem):
+    _copy_pool_rows(idx_ref, table_hbm, rows, sem,
+                    rows.shape[0] * _POOL_BLOCK)
+    out_ref[...] = jnp.sum(rows[...].astype(jnp.float32), axis=0)
 
 
 def gather_pool(table: jax.Array, idx: jax.Array, *,
                 interpret: bool = False) -> jax.Array:
     """table: (N, D); idx: (B, P) int32 -> pooled (B, D) = sum_p table[idx].
 
-    D must be a multiple of 128 (lane width) for the non-interpret path
-    (checked).
+    Each grid step copies the P rows of 8 queries into a (P, 8, D) VMEM
+    block and sums over P into an (8, D) output block.  The compiled path
+    needs 32-bit rows and D % 128 == 0 (checked).
     """
     B, P = idx.shape
     N, D = table.shape
-    _check_lane_width(D, interpret, "gather_pool")
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, D), lambda b, p, idx_ref: (idx_ref[b, p], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, D), lambda b, p, idx_ref: (b, 0)),
-    )
-    return pl.pallas_call(
+    _check_compiled_rows(table, interpret, "gather_pool")
+    blocks = _pooled_blocks(idx)
+    nb = blocks.shape[0]
+    out = pl.pallas_call(
         _gather_pool_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, D), jnp.float32),
+        grid=(nb,),
+        in_specs=[_smem_block(P * _POOL_BLOCK),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((_POOL_BLOCK, D), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb * _POOL_BLOCK, D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((P, _POOL_BLOCK, D), table.dtype),
+                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
-    )(idx.astype(jnp.int32), table)
+    )(blocks, table)
+    return out[:B]
 
 
 # ---------------------------------------------------------------------------
@@ -123,8 +210,14 @@ def gather_pool(table: jax.Array, idx: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 
-def _gather_rows_dequant_kernel(idx_ref, table_ref, scale_ref, out_ref):
-    out_ref[...] = table_ref[...].astype(jnp.float32) * scale_ref[0, 0]
+def _gather_rows_dequant_kernel(idx_ref, sc_ref, table_hbm, out_ref, rows,
+                                sem):
+    def copy(j):
+        return pltpu.make_async_copy(
+            table_hbm.at[pl.ds(idx_ref[0, j], 1)], rows.at[pl.ds(j, 1)], sem)
+
+    _copy_rows(_ROW_BLOCK, copy)
+    out_ref[...] = rows[...].astype(jnp.float32) * sc_ref[...]
 
 
 def gather_rows_dequant(table: jax.Array, scales: jax.Array, idx: jax.Array,
@@ -132,111 +225,115 @@ def gather_rows_dequant(table: jax.Array, scales: jax.Array, idx: jax.Array,
     """table: (N, D) int8/fp8; scales: (N,) fp32; idx: (M,) ->
     (M, D) fp32 = table[idx] * scales[idx, None], dequantized in-kernel.
 
-    Same streaming layout as :func:`gather_rows`: the scalar-prefetched
-    index vector drives both block index maps, so each grid step DMAs one
-    quantized row (D bytes) plus its (1, 1) scale and dequantizes in VMEM
-    — the fp32 row never exists in HBM.  D must be a multiple of 128 on
-    the non-interpret path (checked).
+    Each grid step copies ``_ROW_BLOCK`` quantized rows (D bytes each)
+    into VMEM and multiplies them by their gathered scales there — the
+    fp32 rows never exist in HBM.  A one-row DMA of 1-byte rows is refused
+    by the TPU compiler, so this kernel runs in interpret mode; the
+    compiled path raises (see the module docstring).
     """
     (M,) = idx.shape
     N, D = table.shape
-    _check_lane_width(D, interpret, "gather_rows_dequant")
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(M,),
-        in_specs=[
-            pl.BlockSpec((1, D), lambda m, idx_ref: (idx_ref[m], 0)),
-            pl.BlockSpec((1, 1), lambda m, idx_ref: (idx_ref[m], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, D), lambda m, idx_ref: (m, 0)),
-    )
-    return pl.pallas_call(
+    _check_compiled_rows(table, interpret, "gather_rows_dequant")
+    blocks = _index_blocks(idx, _ROW_BLOCK)
+    nb = blocks.shape[0]
+    sc = scales[blocks.reshape(-1)].reshape(-1, 1)
+    out = pl.pallas_call(
         _gather_rows_dequant_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, D), jnp.float32),
+        grid=(nb,),
+        in_specs=[_smem_block(_ROW_BLOCK),
+                  pl.BlockSpec((_ROW_BLOCK, 1), lambda i: (i, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((_ROW_BLOCK, D), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb * _ROW_BLOCK, D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((_ROW_BLOCK, D), table.dtype),
+                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
-    )(idx.astype(jnp.int32), table, scales.reshape(-1, 1))
+    )(blocks, sc, table)
+    return out[:M]
 
 
-def _gather_pool_dequant_kernel(idx_ref, table_ref, scale_ref, out_ref):
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += table_ref[...].astype(jnp.float32) * scale_ref[0, 0]
+def _gather_pool_dequant_kernel(idx_ref, sc_ref, table_hbm, out_ref, rows,
+                                sem):
+    _copy_pool_rows(idx_ref, table_hbm, rows, sem,
+                    rows.shape[0] * _POOL_BLOCK)
+    out_ref[...] = jnp.sum(rows[...].astype(jnp.float32) * sc_ref[...],
+                           axis=0)
 
 
 def gather_pool_dequant(table: jax.Array, scales: jax.Array, idx: jax.Array,
                         *, interpret: bool = False) -> jax.Array:
     """table: (N, D) int8/fp8; scales: (N,); idx: (B, P) ->
-    (B, D) fp32 = sum_p table[idx] * scales[idx], dequantized in-kernel.
+    (B, D) fp32 = sum_p table[idx] * scales[idx].
 
-    The pooled variant accumulates *dequantized* rows in the revisited
-    VMEM output block, so pooling never materialises per-hot fp32 rows.
-    D must be a multiple of 128 on the non-interpret path (checked).
+    The pooled variant sums *dequantized* rows in VMEM, so pooling never
+    materialises per-hot fp32 rows in HBM.  Interpret mode only for 1-byte
+    rows, like :func:`gather_rows_dequant`.
     """
     B, P = idx.shape
     N, D = table.shape
-    _check_lane_width(D, interpret, "gather_pool_dequant")
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, D), lambda b, p, idx_ref: (idx_ref[b, p], 0)),
-            pl.BlockSpec((1, 1), lambda b, p, idx_ref: (idx_ref[b, p], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, D), lambda b, p, idx_ref: (b, 0)),
-    )
-    return pl.pallas_call(
+    _check_compiled_rows(table, interpret, "gather_pool_dequant")
+    blocks = _pooled_blocks(idx)
+    nb = blocks.shape[0]
+    sc = scales[blocks].reshape(nb, P, _POOL_BLOCK, 1)
+    out = pl.pallas_call(
         _gather_pool_dequant_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, D), jnp.float32),
+        grid=(nb,),
+        in_specs=[_smem_block(P * _POOL_BLOCK),
+                  pl.BlockSpec((None, P, _POOL_BLOCK, 1),
+                               lambda i: (i, 0, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((_POOL_BLOCK, D), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb * _POOL_BLOCK, D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((P, _POOL_BLOCK, D), table.dtype),
+                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
-    )(idx.astype(jnp.int32), table, scales.reshape(-1, 1))
+    )(blocks, sc, table)
+    return out[:B]
 
 
 def _quantize_rows_kernel(rows_ref, q_ref, scale_ref, *, row_format):
     qdtype, qmax = ROW_FORMATS[row_format]
-    row = rows_ref[...].astype(jnp.float32)
-    scale = jnp.max(jnp.abs(row)) / qmax + 1e-12
-    y = row / scale
+    rows = rows_ref[...].astype(jnp.float32)
+    scale = jnp.max(jnp.abs(rows), axis=1, keepdims=True) / qmax + 1e-12
+    y = rows / scale
     if row_format == "int8":
         # jnp.round is round-half-even, bit-identical to np.round — the
         # fidelity suite pins host/device quantizer parity on that.
         y = jnp.clip(jnp.round(y), -qmax, qmax)
     q_ref[...] = y.astype(qdtype)
-    scale_ref[0, 0] = scale
+    scale_ref[...] = scale
 
 
 def quantize_rows(rows: jax.Array, *, row_format: str = "int8",
                   interpret: bool = False):
     """rows: (M, D) float -> ((M, D) quantized, (M,) fp32 per-row scales).
 
-    The populate-side kernel: one grid step per admitted row computes the
-    per-row absmax, derives ``scale = absmax / qmax + 1e-12`` and
-    round/clips (int8) or narrows (fp8) in VMEM — the device-side twin of
-    the host NumPy quantizer the store used to run per admit.  D must be
-    a multiple of 128 on the non-interpret path (checked).
+    The populate-side kernel: each grid step takes a 32-row tile (the
+    int8 sublane tile; M is zero-padded to it), computes the per-row
+    absmax, derives ``scale = absmax / qmax + 1e-12`` and round/clips
+    (int8) or narrows (fp8) in VMEM — the device-side twin of the host
+    NumPy quantizer.  D must be a multiple of 128 on the non-interpret
+    path (checked).
     """
     if row_format not in ROW_FORMATS:
         raise ValueError(f"unknown row_format {row_format!r} "
                          f"(expected one of {sorted(ROW_FORMATS)})")
     M, D = rows.shape
-    _check_lane_width(D, interpret, "quantize_rows")
+    rows = rows.astype(jnp.float32)
+    _check_compiled_rows(rows, interpret, "quantize_rows")
     qdtype, _ = ROW_FORMATS[row_format]
+    mp = max(1, pl.cdiv(M, _QUANT_TILE)) * _QUANT_TILE
     q, scales = pl.pallas_call(
         functools.partial(_quantize_rows_kernel, row_format=row_format),
-        grid=(M,),
-        in_specs=[pl.BlockSpec((1, D), lambda m: (m, 0))],
-        out_specs=[pl.BlockSpec((1, D), lambda m: (m, 0)),
-                   pl.BlockSpec((1, 1), lambda m: (m, 0))],
-        out_shape=[jax.ShapeDtypeStruct((M, D), qdtype),
-                   jax.ShapeDtypeStruct((M, 1), jnp.float32)],
+        grid=(mp // _QUANT_TILE,),
+        in_specs=[pl.BlockSpec((_QUANT_TILE, D), lambda m: (m, 0))],
+        out_specs=[pl.BlockSpec((_QUANT_TILE, D), lambda m: (m, 0)),
+                   pl.BlockSpec((_QUANT_TILE, 1), lambda m: (m, 0))],
+        out_shape=[jax.ShapeDtypeStruct((mp, D), qdtype),
+                   jax.ShapeDtypeStruct((mp, 1), jnp.float32)],
         interpret=interpret,
-    )(rows.astype(jnp.float32))
-    return q, scales.reshape(-1)
+    )(jnp.pad(rows, ((0, mp - M), (0, 0))))
+    return q[:M], scales[:M, 0]
 
 
 def quantize_rows_ref(rows: jax.Array, row_format: str = "int8"):

@@ -1,6 +1,8 @@
 """DLRM serving launcher on tiered memory — the paper's deployment.
 
     PYTHONPATH=src python -m repro.launch.serve --policy recmg --batches 50
+    PYTHONPATH=src python -m repro.launch.serve --published --policy lru \
+        --batches 10 --batch-queries 16      # dlrm-recmg at published widths
 
 Pipeline per inference batch (paper Fig. 6):
   1. embedding lookups go through the TieredEmbeddingStore (device buffer
@@ -14,8 +16,9 @@ Prints the Fig.16-style latency breakdown and hit rates per policy.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,9 +29,65 @@ from repro.core.recmg import RecMGOutputs, precompute_outputs
 from repro.core.serving import MultiTableTieredStore
 from repro.core.tiered import TieredEmbeddingStore
 from repro.core.trace import Trace, TraceGenConfig, generate_trace
-from repro.models.dlrm import init_dlrm
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models.dlrm import init_dlrm_dense
 from repro.obs import MetricsRegistry
 from repro.obs.tracing import get_tracer
+
+
+# Rows per table when the published 72,704 do not fit the host: 856 x 8,192
+# x 128 fp32 rows are 3.6 GB of slow tier.
+CUT_ROWS_PER_TABLE = 8192
+# Host RAM the full published table needs, as a multiple of its own bytes:
+# the trace, its window features and the runtime need room beside it.
+FULL_TABLE_RAM_FACTOR = 1.5
+
+
+def make_host_table(n_rows: int, d: int, seed: int = 0) -> np.ndarray:
+    """The slow tier: ``(n_rows, d)`` float32 rows drawn N(0, 1) from
+    ``seed``.  Filled in place in float32, so a 31.9 GB table never has a
+    float64 twin."""
+    host = np.empty((n_rows, d), np.float32)
+    np.random.default_rng(seed).standard_normal(out=host, dtype=np.float32)
+    return host
+
+
+def host_mem_available() -> Optional[int]:
+    """``MemAvailable`` from ``/proc/meminfo`` in bytes (None off Linux)."""
+    try:
+        with open("/proc/meminfo") as f:
+            for line in f:
+                if line.startswith("MemAvailable:"):
+                    return int(line.split()[1]) * 1024
+    except OSError:
+        return None
+    return None
+
+
+def published_rows_per_table(cfg, mem_available: Optional[int]) -> int:
+    """Rows per table for ``cfg`` at its published widths: all of them
+    when host RAM holds ``FULL_TABLE_RAM_FACTOR`` times the fp32 table,
+    else ``CUT_ROWS_PER_TABLE``."""
+    full_bytes = cfg.n_tables * cfg.rows_per_table * cfg.emb_dim * 4
+    if mem_available is not None \
+            and mem_available >= FULL_TABLE_RAM_FACTOR * full_bytes:
+        return cfg.rows_per_table
+    return min(cfg.rows_per_table, CUT_ROWS_PER_TABLE)
+
+
+@dataclasses.dataclass
+class ServedBatch:
+    """One served batch, handed to ``serve_trace``'s ``probe`` outside the
+    timed window: what went in, what the store served, what came out."""
+    index: int
+    ids: np.ndarray  # (B*T*P,) global row ids in request order
+    rows: jax.Array  # (B*T*P, D) the rows the store served
+    dense: jax.Array  # (B, dense_features) dense input
+    logits: jax.Array  # (B,) served logits
+    host: np.ndarray  # the slow tier the rows came from
+    cfg: object  # the model config served (tables, width, pooling)
+    params: dict  # dense-MLP parameters of the forward
+    store: object  # the store that served the batch
 
 
 def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
@@ -42,7 +101,9 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
                 priority_mix=None, queue_bound: int = 0,
                 fault_plan: str = "", fault_seed: int = 0,
                 replicate_hot: int = 0, quantize: bool = False,
-                row_format: Optional[str] = None, log=None) -> Dict:
+                row_format: Optional[str] = None, log=None,
+                probe: Optional[Callable[[ServedBatch], None]] = None
+                ) -> Dict:
     """Replay a trace as DLRM inference batches through the tiered store.
 
     ``quantize=True`` stores the fast tier quantized (``row_format``:
@@ -105,12 +166,24 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
     (default 4 batches) with lowest-priority-first shedding, EDF batch
     scheduling, deadline-driven degraded answers and prefetch
     backpressure.  The result gains ``admission`` /  ``goodput_rps``
-    keys and the ``adm.*`` metrics namespace."""
+    keys and the ``adm.*`` metrics namespace.
+
+    The slow tier is :func:`make_host_table` over the trace's rows, seed
+    0.  ``probe``, if given, is called with a :class:`ServedBatch` after
+    every batch, outside the timed window (it needs FIFO batches, so not
+    with ``overload``)."""
     T, P = cfg.n_tables, cfg.multi_hot
     per_batch = batch_queries * T * P
-    host_rows = int(trace.rows_per_table.sum())
-    host = np.random.default_rng(0).normal(
-        size=(host_rows, cfg.emb_dim)).astype(np.float32)
+    n_batches = len(trace.global_id) // per_batch
+    if n_batches == 0:
+        raise ValueError(
+            f"the trace holds {len(trace.global_id)} accesses, fewer than "
+            f"one batch of {batch_queries} queries x {T} tables x {P} ids "
+            f"= {per_batch}")
+    if probe is not None and overload:
+        raise ValueError("probe needs FIFO batches; admission control "
+                         "(overload) reorders them")
+    host = make_host_table(int(trace.rows_per_table.sum()), cfg.emb_dim)
     pol = "recmg" if policy == "recmg" else "lru"
     if shards and multi_table:
         raise ValueError("pass at most one of shards / multi_table")
@@ -150,7 +223,6 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
 
     gid = trace.global_id
     rng = np.random.default_rng(1)
-    n_batches = len(gid) // per_batch
     chunk_state = {"ptr": 0}
     compute = {"s": 0.0}
 
@@ -205,7 +277,8 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
         return items
 
     def forward_batch(emb):
-        """Pool + dense forward; returns measured compute seconds.
+        """Pool + dense forward; returns ``(measured compute seconds,
+        logits, dense input)``.
         Partial batches (EDF pops under admission control can close a
         batch below ``max_batch``) are zero-padded to the full shape so
         the jitted forward sees one shape — no per-size XLA recompiles
@@ -224,7 +297,13 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
         jax.block_until_ready(out)
         c = time.perf_counter() - t1
         compute["s"] += c
-        return c
+        return c, out, dense
+
+    def report(b, ids, emb, fwd_out):
+        if probe is not None:
+            _, logits, dense = fwd_out
+            probe(ServedBatch(b, ids, emb, dense, logits, host, cfg, params,
+                              store))
 
     # Warm the jitted dense forward off the measured path: its first-call
     # XLA compile otherwise lands inside batch 0's latency window and
@@ -273,8 +352,14 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
             clock=rt_clock,
             batch_hook=controller.on_batch if controller else None)
 
+        served = {"n": 0}  # FIFO batches: batch b's ids follow b-1's
+
         def step(b, emb):
-            c = forward_batch(emb)
+            fwd_out = forward_batch(emb)
+            c = fwd_out[0]
+            lo = served["n"]
+            served["n"] += emb.shape[0]
+            report(b, gid[lo: served["n"]], emb, fwd_out)
             if log and b % 10 == 0:
                 log(f"batch {b}: hit {store.stats.hit_rate:.3f} "
                     f"stall {rt.telemetry.stall_ms:.1f} ms")
@@ -309,8 +394,9 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
             pre_hits = store.stats.hits
             t0 = time.perf_counter()
             emb = store.lookup(ids)  # (per_batch, D)
-            forward_batch(emb)
+            fwd_out = forward_batch(emb)
             lat.append(time.perf_counter() - t0)
+            report(b, ids, emb, fwd_out)
             # ``stage_model_outputs`` double-buffers: the outputs land at
             # the next batch boundary without blocking an in-flight
             # lookup; the flush runs in the inter-batch gap (outside the
@@ -399,13 +485,16 @@ def _dense_forward(params, cfg, dense, pooled):
     z = jnp.concatenate([bot[:, None, :], pooled.astype(ct)], axis=1)
     zz = jnp.einsum("bfd,bgd->bfg", z, z, preferred_element_type=jnp.float32)
     f = z.shape[1]
-    iu, ju = jnp.triu_indices(f, k=1)
+    # NumPy indices are constants of the program; jnp.triu_indices is
+    # computed in it, and at 857 features takes ~50 s to compile for a v5e.
+    iu, ju = np.triu_indices(f, k=1)
     inter = zz[:, iu, ju]
     top_in = jnp.concatenate([bot.astype(jnp.float32), inter], axis=1)
     return _mlp(params["top"], top_in.astype(ct))[:, 0]
 
 
-def main(argv=None):
+def main(argv=None, probe: Optional[Callable[[ServedBatch], None]] = None):
+    """The serve CLI; ``probe`` is passed through to :func:`serve_trace`."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--policy", default="recmg",
                     choices=["lru", "recmg", "recmg-oracle"])
@@ -417,10 +506,17 @@ def main(argv=None):
                          "deterministic frequency heuristic, or the "
                          "Voyager-class ML prefetcher baseline (prefetch "
                          "stream on an LRU store)")
-    ap.add_argument("--batches", type=int, default=40)
+    ap.add_argument("--published", action="store_true",
+                    help="serve dlrm-recmg at its published widths (856 "
+                         "tables, D=128, P=20, published MLPs) instead of "
+                         "the reduced smoke config; only the batch count "
+                         "and, when host RAM is short, the rows per table "
+                         f"(to {CUT_ROWS_PER_TABLE}) are cut")
+    ap.add_argument("--batches", type=int, default=40,
+                    help="batches to serve; the trace holds exactly "
+                         "batches x batch-queries x tables x pooling ids")
     ap.add_argument("--batch-queries", type=int, default=32)
     ap.add_argument("--capacity-frac", type=float, default=0.2)
-    ap.add_argument("--accesses", type=int, default=200_000)
     ap.add_argument("--train-epochs", type=int, default=3)
     ap.add_argument("--quantize", action="store_true",
                     help="store the fast tier quantized (per-row scales); "
@@ -508,9 +604,32 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.overload:
         args.async_prefetch = True
+    enable_compile_cache()
 
-    cfg = get_config("dlrm-recmg").reduced()
-    params = init_dlrm(jax.random.PRNGKey(0), cfg)
+    cfg = get_config("dlrm-recmg")
+    if args.published:
+        mem = host_mem_available()
+        rows = published_rows_per_table(cfg, mem)
+        print(f"{cfg.name} at published widths: {cfg.n_tables} tables, "
+              f"D={cfg.emb_dim}, P={cfg.multi_hot}, "
+              f"dense={cfg.dense_features}, bottom={cfg.bottom_mlp}, "
+              f"top={cfg.top_mlp}")
+        if rows != cfg.rows_per_table:
+            full_gb = cfg.n_tables * cfg.rows_per_table * cfg.emb_dim * 4e-9
+            avail = "unknown" if mem is None else f"{mem * 1e-9:.1f} GB"
+            print(f"cut: rows_per_table {cfg.rows_per_table} -> {rows} "
+                  f"(host RAM available {avail} < {FULL_TABLE_RAM_FACTOR} "
+                  f"x the {full_gb:.1f} GB fp32 table)")
+            cfg = dataclasses.replace(cfg, rows_per_table=rows)
+    else:
+        cfg = cfg.reduced()
+    accesses = args.batches * args.batch_queries * cfg.n_tables \
+        * cfg.multi_hot
+    print(f"cut: {args.batches} batches x {args.batch_queries} queries "
+          f"({accesses} accesses)")
+    # Serving reads embedding rows from the host tier: only the dense
+    # MLPs live on the device.
+    params = init_dlrm_dense(jax.random.PRNGKey(0), cfg)
 
     if args.workload:
         from repro.workloads import make_trace, parse_workload
@@ -519,12 +638,12 @@ def main(argv=None):
         if spec.regime != "replay":  # replay: the file's geometry wins
             spec = spec.with_(n_tables=cfg.n_tables,
                               rows_per_table=cfg.rows_per_table,
-                              n_accesses=args.accesses)
+                              n_accesses=accesses)
         trace = make_trace(spec)
     else:
         tr_cfg = TraceGenConfig(
             n_tables=cfg.n_tables, rows_per_table=cfg.rows_per_table,
-            n_accesses=args.accesses, drift_every=10**9,
+            n_accesses=accesses, drift_every=10**9,
         )
         trace = generate_trace(tr_cfg)
     capacity = int(args.capacity_frac * trace.unique_count())
@@ -604,7 +723,7 @@ def main(argv=None):
                           replicate_hot=args.replicate_hot,
                           quantize=args.quantize,
                           row_format=args.row_format if args.quantize
-                          else None, log=print)
+                          else None, log=print, probe=probe)
     finally:
         if tracer is not None:
             install_tracer(None)

@@ -16,6 +16,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.sharding.partition import constrain_batch
@@ -47,21 +48,30 @@ def num_interactions(cfg: ModelConfig) -> int:
     return f * (f - 1) // 2
 
 
+def init_dlrm_dense(key, cfg: ModelConfig):
+    """The dense half of :func:`init_dlrm`: bottom and top MLPs only, the
+    same values for the same key.  Serving reads embedding rows from the
+    host tier, so it builds no device table (856 x 72,704 x 128 would be
+    31.9 GB in fp32)."""
+    _, kb, ktop = jax.random.split(key, 3)
+    dt = jnp.dtype(cfg.param_dtype)
+    bot_dims = (cfg.dense_features,) + tuple(cfg.bottom_mlp)
+    top_in = cfg.emb_dim + num_interactions(cfg)
+    top_dims = (top_in,) + tuple(cfg.top_mlp)
+    return {
+        "bottom": _init_mlp(kb, bot_dims, dt),
+        "top": _init_mlp(ktop, top_dims, dt),
+    }
+
+
 def init_dlrm(key, cfg: ModelConfig):
-    kt, kb, ktop = jax.random.split(key, 3)
+    kt = jax.random.split(key, 3)[0]
     dt = jnp.dtype(cfg.param_dtype)
     emb = (
         jax.random.normal(kt, (cfg.n_tables, cfg.rows_per_table, cfg.emb_dim))
         * (1.0 / math.sqrt(cfg.emb_dim))
     ).astype(dt)
-    bot_dims = (cfg.dense_features,) + tuple(cfg.bottom_mlp)
-    top_in = cfg.emb_dim + num_interactions(cfg)
-    top_dims = (top_in,) + tuple(cfg.top_mlp)
-    return {
-        "emb": emb,
-        "bottom": _init_mlp(kb, bot_dims, dt),
-        "top": _init_mlp(ktop, top_dims, dt),
-    }
+    return {"emb": emb, **init_dlrm_dense(key, cfg)}
 
 
 def embedding_lookup_rowsharded(emb, sparse_idx, mesh):
@@ -143,7 +153,9 @@ def dlrm_forward(params, cfg: ModelConfig, dense, sparse_idx,
     z = jnp.concatenate([bot[:, None, :], pooled], axis=1)  # (B, F, D)
     zz = jnp.einsum("bfd,bgd->bfg", z, z, preferred_element_type=jnp.float32)
     f = z.shape[1]
-    iu, ju = jnp.triu_indices(f, k=1)
+    # NumPy indices are constants of the program; jnp.triu_indices is
+    # computed in it, and at 857 features takes ~50 s to compile for a v5e.
+    iu, ju = np.triu_indices(f, k=1)
     inter = zz[:, iu, ju]  # (B, F*(F-1)/2)
     top_in = jnp.concatenate([bot.astype(jnp.float32), inter], axis=1)
     logit = _mlp(params["top"], top_in.astype(ct))[:, 0]

@@ -20,7 +20,8 @@ def test_this_process_has_one_device():
 
 @pytest.mark.slow
 def test_dryrun_cell_subprocess(tmp_path):
-    env = dict(os.environ, PYTHONPATH=str(ROOT / "src"))
+    env = dict(os.environ, PYTHONPATH=str(ROOT / "src"),
+               JAX_PLATFORMS="cpu")  # placeholder devices only
     cmd = [sys.executable, "-m", "repro.launch.dryrun",
            "--arch", "smollm-135m", "--shape", "decode_32k",
            "--mesh", "both", "--out", str(tmp_path), "--tag", "t"]

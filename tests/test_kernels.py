@@ -51,6 +51,31 @@ def test_gather_rows(N, D, M, dtype):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(table[idx]))
 
 
+@pytest.mark.parametrize("M,n_valid", [(16, 5), (1030, 1025), (2048, 0)])
+def test_gather_rows_n_valid(M, n_valid):
+    """Only the first ``n_valid`` rows are copied (a padded bucket pays for
+    the rows it uses); those match table[idx] exactly, across SMEM index
+    blocks, with ``n_valid`` traced."""
+    table = jax.random.normal(jax.random.PRNGKey(0), (300, 128), jnp.float32)
+    idx = jax.random.randint(jax.random.PRNGKey(1), (M,), 0, 300)
+    out = jax.jit(lambda t, i, n: gather_rows(t, i, n, interpret=True))(
+        table, idx, jnp.int32(n_valid))
+    assert out.shape == (M, 128)
+    np.testing.assert_array_equal(np.asarray(out[:n_valid]),
+                                  np.asarray(table[idx[:n_valid]]))
+
+
+def test_one_byte_rows_refused_on_compiled_path():
+    """The TPU compiler refuses a one-row DMA of 1-byte rows, so the
+    compiled path says so instead of failing inside Mosaic."""
+    q, s = quantize_rows_ref(jnp.zeros((16, 128), jnp.float32), "int8")
+    with pytest.raises(ValueError, match="32-bit rows"):
+        gather_rows_dequant(q, s, jnp.zeros((4,), jnp.int32))
+    with pytest.raises(ValueError, match="32-bit rows"):
+        gather_rows(jnp.zeros((16, 128), jnp.bfloat16),
+                    jnp.zeros((4,), jnp.int32))
+
+
 @pytest.mark.parametrize("N,D,M", [
     (256, 128, 16),
     (64, 256, 33),

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 from jax.flatten_util import ravel_pytree
 
 from repro.core.caching_model import (CachingModelConfig, bce_loss,
@@ -74,7 +73,7 @@ def _int_batch(rng, b, t):
 
 
 def test_bce_loss_gradient_matches_finite_differences():
-    with enable_x64():
+    with jax.enable_x64(True):
         cfg = CachingModelConfig(n_tables=N_TABLES, table_emb=4, row_emb=4,
                                  hidden=HIDDEN, in_len=IN_LEN)
         params = init_caching_model(jax.random.PRNGKey(0), cfg)
@@ -105,7 +104,7 @@ def test_prefetch_loss_gradient_matches_detached_target_fd(loss):
     AND the detach really cuts the target branch (if it leaked, the
     analytic grad would pick up the extra embedding-table terms and the
     comparison would blow past the f64 tolerance)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         cfg, params, batch = _prefetch_case(loss)
         wlen = cfg.window if loss == "chamfer" else cfg.out_len
         w0 = jax.lax.stop_gradient(access_reps(
@@ -139,7 +138,7 @@ def test_prefetch_loss_gradient_matches_detached_target_fd(loss):
 def test_set_loss_terms_gradient_wrt_points(term):
     """The chamfer / truncated-L2 / diversity terms FD-checked directly
     with respect to the predicted point set (no model, no detach)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(3)
         po0 = jnp.asarray(rng.normal(size=(2, OUT_LEN, 5)))
         w = jnp.asarray(rng.normal(size=(2, 3 * OUT_LEN, 5)))

@@ -118,6 +118,24 @@ def test_kernel_gather_matches_jit_gather(host):
     st_ker.check_invariants()
 
 
+@pytest.mark.parametrize("backend,d,dtype,want", [
+    ("tpu", 128, jnp.float32, True),
+    ("tpu", 256, jnp.float32, True),
+    ("tpu", 128, jnp.int8, False),  # quantized rows: XLA gather on TPU
+    ("tpu", 128, jnp.float8_e4m3fn, False),
+    ("tpu", 128, jnp.bfloat16, False),
+    ("tpu", 96, jnp.float32, False),
+    ("cpu", 128, jnp.float32, False),
+])
+def test_kernel_gather_rule(backend, d, dtype, want):
+    """The documented rule (docs/architecture.md, "The quantized fast
+    tier"): the compiled Pallas gather serves only 32-bit rows with
+    D % 128 == 0 on a TPU."""
+    from repro.core.tiered import kernel_gather_ok
+
+    assert kernel_gather_ok(backend, d, dtype) is want
+
+
 def test_fp8_store_roundtrip(host):
     st = TieredEmbeddingStore(host, 32, quantize=True, row_format="fp8",
                               warmup_batch=32)

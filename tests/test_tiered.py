@@ -121,6 +121,23 @@ def test_use_kernel_with_quantize_honored(host):
     assert np.abs(out - host[ids]).max() / np.abs(host).max() < 0.02
 
 
+def test_fp32_kernel_store_matches_xla_store():
+    """The fp32 kernel path (interpret mode) copies only the unique rows of
+    each padded bucket and returns exactly what the XLA gather returns,
+    overflow fold included."""
+    host = np.random.default_rng(1).normal(size=(400, 128)).astype(np.float32)
+    xla = TieredEmbeddingStore(host, capacity=48, use_kernel=False)
+    ker = TieredEmbeddingStore(host, capacity=48, use_kernel=True,
+                               kernel_interpret=True)
+    rng = np.random.default_rng(2)
+    for n in (20, 37, 90):  # 90 unique-ish ids overflow the 48-row buffer
+        ids = rng.integers(0, 400, size=n)
+        want = np.asarray(xla.lookup(ids))
+        np.testing.assert_array_equal(np.asarray(ker.lookup(ids)), want)
+        np.testing.assert_array_equal(want, host[ids])
+    ker.check_invariants()
+
+
 def test_use_kernel_unsupported_combos_raise(host):
     """An explicit ``use_kernel=True`` is a contract: unsupported setups
     raise instead of silently downgrading (auto mode may still fall
