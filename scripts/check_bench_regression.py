@@ -7,8 +7,10 @@ Two metrics guard the serving hot path:
 * ``batched_lookup_rows_per_s`` (bench ``tentpole``) — absolute batched
   lookup throughput; a floor metric (machine-dependent, so the baseline
   is deliberately conservative and the tolerance generous).
-* ``recmg_lru_p50_ratio`` (bench ``fig16``) — measured p50 batch latency
-  of the recmg policy relative to LRU; a ceiling metric (machine-
+* ``recmg_lru_p50_ratio`` (bench ``fig16``) — measured p50 of a batch's
+  lookup, pooling and forward steps (its latency without the flush of the
+  previous batch's model outputs) under the recmg policy relative to
+  LRU; a ceiling metric (machine-
   independent: both sides run on the same box, so this is the true guard
   against the ML policy's bookkeeping creeping back onto the hot path).
 

@@ -104,6 +104,7 @@ class MultiTableTieredStore:
         # comparison measures policy quality, not aggregation artifacts).
         self.fetch_us_fixed = float(fetch_us_fixed)
         self._fixed_fetch_s = 0.0
+        self._h2d_bytes = 0  # the reassembled batches sent to the device
         self.stores: List[TieredEmbeddingStore] = [
             TieredEmbeddingStore(t, int(c), policy=policy, quantize=quantize,
                                  row_format=row_format,
@@ -178,6 +179,7 @@ class MultiTableTieredStore:
             missed = missed or st.stats.on_demand_rows > od0
         if missed:
             self._fixed_fetch_s += self.fetch_us_fixed * 1e-6
+        self._h2d_bytes += out.nbytes
         return jnp.asarray(out)
 
     def _route_outputs(self, trunk, bits, prefetch_ids, staged: bool):
@@ -226,6 +228,7 @@ class MultiTableTieredStore:
             agg.merge(s.stats)
         agg.batches = self.batches  # facade batches, not per-store sum
         agg.modeled_fetch_s += self._fixed_fetch_s
+        agg.h2d_bytes += self._h2d_bytes
         return agg
 
     def modeled_batch_ms(self) -> float:

@@ -104,6 +104,7 @@ class ShardedTieredStore:
                           else self.stores[0].buffer.dtype)
         self.batches = 0
         self._fixed_fetch_s = 0.0
+        self._h2d_bytes = 0  # the reassembled batches sent to the device
         # ---- load / critical-path telemetry ----
         self._shard_lookups = np.zeros(self.n_shards, np.int64)
         self._max_batch_imbalance = 0.0
@@ -287,6 +288,7 @@ class ShardedTieredStore:
             # Workers fetch in parallel; modeled time moves by the batch's
             # critical path (what timeliness is measured against).
             self.clock.advance(critical_us)
+        self._h2d_bytes += out.nbytes
         return jnp.asarray(out)
 
     # ---------------- fault handling (armed via arm_faults) ----------------
@@ -537,6 +539,7 @@ class ShardedTieredStore:
             agg.merge(st.stats)
         agg.batches = self.batches  # facade batches, not per-shard sum
         agg.modeled_fetch_s += self._fixed_fetch_s
+        agg.h2d_bytes += self._h2d_bytes
         return agg
 
     def modeled_batch_ms(self) -> float:

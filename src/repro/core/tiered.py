@@ -74,7 +74,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.buffer_manager import RecMGBuffer
-from repro.obs.tracing import get_tracer
+from repro.obs.tracing import Steps, get_tracer
 
 
 # Quantized fast-tier row formats: storage dtype per format (scale stays
@@ -125,13 +125,19 @@ _JIT_GATHER_Q_OV = jax.jit(
     lambda buf, sc, iv, ov, hr:
     jnp.where(ov[:, None], hr,
               buf[iv[0]].astype(jnp.float32) * sc[iv[0]][:, None])[iv[1]])
-_JIT_SCATTER = jax.jit(lambda buf, idx, rows: buf.at[idx].set(rows),
-                       donate_argnums=(0,))
-_JIT_SCATTER_SC = jax.jit(lambda sc, idx, s: sc.at[idx].set(s),
-                          donate_argnums=(0,))
+
+# The writes into the fast tier run as programs named ``store_write*``
+# (``jit_store_write``, ``jit_store_write_quant``, ...), so a profile finds
+# them by name.
+def store_write(buf, idx, rows):
+    """Scatter admitted rows into their slots of the fast tier."""
+    return buf.at[idx].set(rows)
 
 
-def _scatter_quant(buf, sc, idx, rows, row_format):
+_JIT_STORE_WRITE = jax.jit(store_write, donate_argnums=(0,))
+
+
+def store_write_quant(buf, sc, idx, rows, row_format):
     """Fused device-side quantize + scatter: per-row scale derivation,
     round/clip and both buffer writes trace into ONE jitted program, so
     the quantized admit keeps the fp32 path's single-dispatch /
@@ -143,8 +149,8 @@ def _scatter_quant(buf, sc, idx, rows, row_format):
     return buf.at[idx].set(q), sc.at[idx].set(s)
 
 
-_JIT_SCATTER_Q = jax.jit(_scatter_quant, static_argnums=(4,),
-                         donate_argnums=(0, 1))
+_JIT_STORE_WRITE_Q = jax.jit(store_write_quant, static_argnums=(4,),
+                             donate_argnums=(0, 1))
 
 _KERNEL_JITS: Dict[tuple, object] = {}
 
@@ -210,11 +216,13 @@ def _kernel_scatter_q(row_format: str, interpret: bool = False):
     if key not in _KERNEL_JITS:
         from repro.kernels import embedding_gather as eg
 
-        def qs(buf, sc, idx, rows, _rf=row_format, _i=interpret):
+        def store_write_quant_kernel(buf, sc, idx, rows, _rf=row_format,
+                                     _i=interpret):
             q, s = eg.quantize_rows(rows, row_format=_rf, interpret=_i)
             return buf.at[idx].set(q), sc.at[idx].set(s)
 
-        _KERNEL_JITS[key] = jax.jit(qs, donate_argnums=(0, 1))
+        _KERNEL_JITS[key] = jax.jit(store_write_quant_kernel,
+                                    donate_argnums=(0, 1))
     return _KERNEL_JITS[key]
 
 
@@ -227,10 +235,28 @@ class TierStats:
     prefetch_hits: int = 0
     on_demand_rows: int = 0
     evictions: int = 0
-    fetch_s: float = 0.0  # measured host->device copy time
-    gather_s: float = 0.0  # device gather time
-    model_s: float = 0.0  # CPU-side model inference time (off critical path)
+    fetch_s: float = 0.0  # miss path: slow_read_s + residency_s + write_s
+    gather_s: float = 0.0  # gather_dispatch_s + sync_s
+    model_s: float = 0.0  # applying RecMG outputs: rank_s + prefetch_s
     modeled_fetch_s: float = 0.0  # analytic slow-tier penalty
+    # Steps of a lookup and of applying model outputs, in host seconds,
+    # always on (one perf_counter reading per boundary), then counts:
+    partition_s: float = 0.0  # unique, slot map, hit/miss, pf hits, touch
+    slow_read_s: float = 0.0  # host read of the missed rows
+    residency_s: float = 0.0  # admission and eviction
+    write_s: float = 0.0  # dispatch of the write into the fast tier
+    gather_dispatch_s: float = 0.0  # gather operands + dispatch
+    sync_s: float = 0.0  # device sync of the lookup's result
+    rank_s: float = 0.0  # RecMG priorities of the accessed chunks
+    prefetch_s: float = 0.0  # RecMG prefetch admission and priorities
+    populate_calls: int = 0  # apply_model_outputs calls
+    write_rows: int = 0  # rows written into the fast tier (before padding)
+    overflow_rows: int = 0  # rows served through the overflow select
+    h2d_bytes: int = 0  # bytes of the host arrays lookups and writes send
+
+    SECONDS = ("partition_s", "slow_read_s", "residency_s", "write_s",
+               "gather_dispatch_s", "sync_s", "rank_s", "prefetch_s")
+    COUNTS = ("populate_calls", "write_rows", "overflow_rows", "h2d_bytes")
 
     @property
     def hit_rate(self):
@@ -253,12 +279,19 @@ class TierStats:
             "modeled_fetch_s": round(self.modeled_fetch_s, 4),
         }
 
+    def steps(self) -> Dict[str, float]:
+        """The step seconds and counts, with the totals they tile."""
+        return {f: getattr(self, f) for f in
+                ("fetch_s", "gather_s", "model_s") + self.SECONDS
+                + self.COUNTS}
+
     def merge(self, other: "TierStats") -> "TierStats":
         """Aggregate (for the multi-table facade)."""
         for f in ("batches", "lookups", "hits", "misses", "prefetch_hits",
-                  "on_demand_rows", "evictions"):
+                  "on_demand_rows", "evictions") + self.COUNTS:
             setattr(self, f, getattr(self, f) + getattr(other, f))
-        for f in ("fetch_s", "gather_s", "model_s", "modeled_fetch_s"):
+        for f in ("fetch_s", "gather_s", "model_s",
+                  "modeled_fetch_s") + self.SECONDS:
             setattr(self, f, getattr(self, f) + getattr(other, f))
         return self
 
@@ -275,6 +308,8 @@ class TierStats:
             ("time.gather_s", self.gather_s),
             ("time.model_s", self.model_s),
             ("time.modeled_fetch_s", self.modeled_fetch_s),
+            *((f"time.{f}", getattr(self, f)) for f in self.SECONDS),
+            *((f"steps.{f}", getattr(self, f)) for f in self.COUNTS),
         ):
             reg.counter(f"{prefix}.{key}").inc(val)
         reg.gauge(f"{prefix}.fast.hit_rate").set(self.hit_rate)
@@ -393,7 +428,7 @@ class TieredEmbeddingStore:
             else:
                 rf = self.row_format
                 self._scatter_q = lambda buf, sc, idx, rows: \
-                    _JIT_SCATTER_Q(buf, sc, idx, rows, rf)
+                    _JIT_STORE_WRITE_Q(buf, sc, idx, rows, rf)
         if warmup_batch:
             self.warmup(warmup_batch)
 
@@ -493,8 +528,8 @@ class TieredEmbeddingStore:
                     self.buffer, self.scales, slots, rows)
             else:
                 r0 = np.repeat(np.asarray(self.buffer[0:1]), b, axis=0)
-                self.buffer = _JIT_SCATTER(self.buffer, slots,
-                                           jnp.asarray(r0))
+                self.buffer = _JIT_STORE_WRITE(self.buffer, slots,
+                                               jnp.asarray(r0))
             b <<= 1
         jax.block_until_ready(self.buffer)
 
@@ -668,130 +703,157 @@ class TieredEmbeddingStore:
         forward); facades that merge sub-results host-side should use
         :meth:`lookup_host` instead, which saves the device-side slice.
         """
-        out, m_ids, t0 = self._lookup_padded(ids)
-        out = out[:m_ids]
-        jax.block_until_ready(out)
-        self.stats.gather_s += time.perf_counter() - t0
-        return out
+        return self._lookup(ids, to_host=False)
 
     def lookup_host(self, ids: np.ndarray) -> np.ndarray:
         """:meth:`lookup` materialized as a NumPy array in one transfer —
         the multi-table and sharded facades reassemble per-store results
         on the host, so slicing there is free.  Counters are identical to
         :meth:`lookup`."""
-        out, m_ids, t0 = self._lookup_padded(ids)
-        out = np.asarray(out)[:m_ids]
-        self.stats.gather_s += time.perf_counter() - t0
+        return self._lookup(ids, to_host=True)
+
+    def _lookup(self, ids: np.ndarray, to_host: bool):
+        """Shared lookup pipeline, timed as consecutive steps under the
+        ``store.lookup`` span (one clock reading per boundary,
+        :class:`~repro.obs.tracing.Steps`): ``partition`` | ``admit`` =
+        ``slow_read`` + ``residency`` + ``write`` (= ``fetch_s``) |
+        ``partition`` again for the post-admission slot map and the LRU
+        touch | ``gather`` (dispatch) +
+        ``sync`` (= ``gather_s``)."""
+        self._drain_staged()
+        st = self.stats
+        steps = Steps(get_tracer())
+        with steps.group("store", "lookup", track="store") as lookup_span:
+            out, m_ids, t_gather = self._lookup_padded(ids, steps,
+                                                       lookup_span)
+            with steps.step(st, "sync_s", "store", "sync", track="store"):
+                if to_host:
+                    out = np.asarray(out)[:m_ids]
+                else:
+                    out = out[:m_ids]
+                    jax.block_until_ready(out)
+        st.gather_s += steps.t - t_gather
         return out
 
-    def _lookup_padded(self, ids: np.ndarray):
-        """Shared lookup pipeline; returns (padded device rows, true batch
-        size, gather timer start) — callers slice and sync."""
-        self._drain_staged()
-        tr = get_tracer()
-        if tr.enabled:  # off cost: one global read + attr check per batch
-            t_span = tr.clock.now()
-            ev0 = self.stats.evictions
-        ids = np.asarray(ids).ravel()
-        self.stats.batches += 1
-        self.stats.lookups += ids.size
-        uniq, inv = np.unique(ids, return_inverse=True)
-        slots_u = self._slot_map[uniq]
-        miss_mask = slots_u < 0
-        n_hit = int(np.count_nonzero(~miss_mask[inv]))
-        self.stats.hits += n_hit
-        self.stats.misses += int(ids.size) - n_hit
-        hit_slots = slots_u[~miss_mask]
-        pf = self._pf_flag[hit_slots]
-        n_pf = int(np.count_nonzero(pf))
-        if n_pf:  # first-touch prefetch attribution
-            self.stats.prefetch_hits += n_pf
-            self._pf_flag[hit_slots] = False
+    def _lookup_padded(self, ids: np.ndarray, steps: Steps, lookup_span):
+        """The lookup's steps up to the gather's dispatch; returns (padded
+        device rows, true batch size, the reading the gather started at)
+        and sets the lookup span's args."""
+        st = self.stats
+        ev0 = st.evictions
+        with steps.step(st, "partition_s", "store", "partition",
+                        track="store"):
+            ids = np.asarray(ids).ravel()
+            st.batches += 1
+            st.lookups += ids.size
+            uniq, inv = np.unique(ids, return_inverse=True)
+            slots_u = self._slot_map[uniq]
+            miss_mask = slots_u < 0
+            n_hit = int(np.count_nonzero(~miss_mask[inv]))
+            st.hits += n_hit
+            st.misses += int(ids.size) - n_hit
+            hit_slots = slots_u[~miss_mask]
+            pf = self._pf_flag[hit_slots]
+            n_pf = int(np.count_nonzero(pf))
+            if n_pf:  # first-touch prefetch attribution
+                st.prefetch_hits += n_pf
+                self._pf_flag[hit_slots] = False
+            missing = uniq[miss_mask]
 
-        missing = uniq[miss_mask]
         if missing.size:
-            t0 = time.perf_counter()
-            if tr.enabled:
-                t_admit = tr.clock.now()
-            rows = self.host[missing]
-            kept = self._admit(missing)
-            wkeys = missing[kept]
-            self._write_rows(self._slot_map[wkeys], rows[kept])
-            # No sync here: the scatter pipelines into the gather below and
-            # both resolve in that single device sync (fetch_s is the
-            # host-side admit + dispatch time; execution lands in gather_s).
-            self.stats.fetch_s += time.perf_counter() - t0
-            self.stats.on_demand_rows += int(missing.size)
-            self.stats.modeled_fetch_s += (
-                self.fetch_us_fixed + self.fetch_us_per_row * missing.size
-            ) * 1e-6
-            if tr.enabled:
-                tr.add_span("store", "admit", t_admit,
-                            tr.clock.now() - t_admit, track="store",
-                            args={"miss_rows": int(missing.size)})
-            slots_u = self._slot_map[uniq]  # refresh post-admission
+            t_admit = steps.t
+            with steps.group("store", "admit", track="store",
+                             miss_rows=int(missing.size)):
+                with steps.step(st, "slow_read_s", "store", "slow_read",
+                                track="store"):
+                    rows = self.host[missing]
+                with steps.step(st, "residency_s", "store", "residency",
+                                track="store"):
+                    kept = self._admit(missing)
+                with steps.step(st, "write_s", "store", "write",
+                                track="store"):
+                    wkeys = missing[kept]
+                    self._write_rows(self._slot_map[wkeys], rows[kept])
+                    # No sync here: the scatter pipelines into the
+                    # gather below and both resolve in that single
+                    # device sync (fetch_s is the host-side admit +
+                    # dispatch time; execution lands in gather_s).
+            st.fetch_s += steps.t - t_admit
 
-        if self.policy == "lru":
-            # Batched touch: every resident key of this batch moves to the
-            # MRU end, ordered by sorted-unique position (seed order).
-            res = slots_u >= 0
-            rs = slots_u[res]
-            self._last_use[rs] = self._clock + np.flatnonzero(res)
-            self._clock += uniq.size
+        if missing.size or self.policy == "lru":
+            with steps.step(st, "partition_s", "store", "partition",
+                            track="store"):
+                if missing.size:
+                    st.on_demand_rows += int(missing.size)
+                    st.modeled_fetch_s += (
+                        self.fetch_us_fixed
+                        + self.fetch_us_per_row * missing.size) * 1e-6
+                    slots_u = self._slot_map[uniq]  # post-admission
+                if self.policy == "lru":
+                    # Batched touch: every resident key of this batch
+                    # moves to the MRU end, ordered by sorted-unique
+                    # position (seed order).
+                    res = slots_u >= 0
+                    rs = slots_u[res]
+                    self._last_use[rs] = self._clock + np.flatnonzero(res)
+                    self._clock += uniq.size
 
-        t0 = time.perf_counter()
-        if tr.enabled:
-            t_gather = tr.clock.now()
-        # Device-resident gather: one fused jitted pass does the slot
-        # gather, the overflow where-select, and the unique->request
-        # expansion, so the result never bounces through the host.  The
-        # two index vectors are packed into one (2, bucket) operand — a
-        # single transfer — and share ONE power-of-two bucket (u <= M
-        # always): independent buckets would give O(log^2) compiled shape
-        # combos, and per-table sub-batch sizes vary enough to hit them
-        # all at runtime.  Buckets are warmed eagerly by :meth:`warmup`.
+        u = uniq.size
+        m_ids = ids.size
+        t_gather = steps.t
+        with steps.step(st, "gather_dispatch_s", "store", "gather",
+                        track="store", uniq=int(u)):
+            out = self._gather(uniq, slots_u, inv, m_ids)
+        # Span args carry the batch's exact counter deltas — the trace
+        # <-> metrics reconciliation sums these over all lookup spans.
+        lookup_span.set(ids=m_ids, uniq=int(u), hit_ids=n_hit,
+                        miss_ids=m_ids - n_hit,
+                        miss_rows=int(missing.size),
+                        evictions=st.evictions - ev0)
+        return out, m_ids, t_gather
+
+    def _gather(self, uniq: np.ndarray, slots_u: np.ndarray,
+                inv: np.ndarray, m_ids: int):
+        """Dispatch the batch's device gather; returns the padded rows.
+
+        One fused jitted pass does the slot gather, the overflow
+        where-select, and the unique->request expansion, so the result
+        never bounces through the host.  The two index vectors are packed
+        into one (2, bucket) operand — a single transfer — and share ONE
+        power-of-two bucket (u <= M always): independent buckets would
+        give O(log^2) compiled shape combos, and per-table sub-batch sizes
+        vary enough to hit them all at runtime.  Buckets are warmed
+        eagerly by :meth:`warmup`."""
         gather_args = (
             (self.buffer, self.scales) if self.quantize else (self.buffer,)
         )
         u = uniq.size
-        m_ids = ids.size
         bsz = _bucket(m_ids)
         iv = np.zeros((2, bsz), np.int32)
         np.maximum(slots_u, 0, out=iv[0, :u], casting="unsafe")
         iv[1, :m_ids] = inv
         overflow = slots_u < 0
-        if overflow.any():
-            # A batch whose unique working set exceeds the buffer can evict
-            # rows admitted earlier in the same batch; stage those rows
-            # from the host tier into the padded gather input and fold them
-            # in with a jitted where-select (counted as on-demand already).
-            ov = np.zeros(bsz, bool)
-            ov[:u] = overflow
-            hrows = np.zeros((bsz, self.host.shape[1]),
-                             self._out_np_dtype)
-            hrows[:u][overflow] = self.host[uniq[overflow]]
-            out = self._gather_ov(*gather_args, jnp.asarray(iv),
-                                  jnp.asarray(ov), jnp.asarray(hrows))
-        else:
-            out = self._gather_inv(*gather_args, jnp.asarray(iv))
-        if tr.enabled:
-            tr.add_span("store", "gather", t_gather,
-                        tr.clock.now() - t_gather, track="store",
-                        args={"uniq": int(u)})
-            # Span args carry the batch's exact counter deltas — the trace
-            # <-> metrics reconciliation sums these over all lookup spans.
-            tr.add_span("store", "lookup", t_span, tr.clock.now() - t_span,
-                        track="store", args={
-                            "ids": m_ids, "uniq": int(u),
-                            "hit_ids": n_hit, "miss_ids": m_ids - n_hit,
-                            "miss_rows": int(missing.size),
-                            "evictions": self.stats.evictions - ev0,
-                        })
-        return out, m_ids, t0
+        n_over = int(np.count_nonzero(overflow))
+        if not n_over:
+            self.stats.h2d_bytes += iv.nbytes
+            return self._gather_inv(*gather_args, jnp.asarray(iv))
+        # A batch whose unique working set exceeds the buffer can evict
+        # rows admitted earlier in the same batch; stage those rows from
+        # the host tier into the padded gather input and fold them in with
+        # a jitted where-select (counted as on-demand already).
+        ov = np.zeros(bsz, bool)
+        ov[:u] = overflow
+        hrows = np.zeros((bsz, self.host.shape[1]), self._out_np_dtype)
+        hrows[:u][overflow] = self.host[uniq[overflow]]
+        self.stats.overflow_rows += n_over
+        self.stats.h2d_bytes += iv.nbytes + ov.nbytes + hrows.nbytes
+        return self._gather_ov(*gather_args, jnp.asarray(iv),
+                               jnp.asarray(ov), jnp.asarray(hrows))
 
     def _write_rows(self, slots: np.ndarray, rows: np.ndarray):
         if not len(slots):
             return
+        self.stats.write_rows += len(slots)
         # Bucket-pad the scatter like the gather: repeat the last
         # (slot, row) pair — rewriting one slot with its own row is a
         # no-op, and the fixed shapes keep XLA from recompiling per batch.
@@ -804,12 +866,14 @@ class TieredEmbeddingStore:
             # quantizer on the kernel path, jnp reference otherwise): no
             # host NumPy pass, and the write pipelines into the batch's
             # gather exactly like the fp32 scatter does.
+            rows = np.asarray(rows, np.float32)
             self.buffer, self.scales = self._scatter_q(
                 self.buffer, self.scales, jnp.asarray(slots),
-                jnp.asarray(rows, jnp.float32))
+                jnp.asarray(rows))
         else:
-            self.buffer = _JIT_SCATTER(
+            self.buffer = _JIT_STORE_WRITE(
                 self.buffer, jnp.asarray(slots), jnp.asarray(rows))
+        self.stats.h2d_bytes += slots.nbytes + rows.nbytes
 
     # ---------------- RecMG co-management hooks ----------------
 
@@ -830,16 +894,35 @@ class TieredEmbeddingStore:
     def _drain_staged(self):
         if self._staged:
             staged, self._staged = self._staged, []
-            for trunk, bits, pf in staged:
-                self.apply_model_outputs(trunk, bits, pf)
+            self._populate(staged)
 
     def apply_model_outputs(self, trunk: np.ndarray, bits: np.ndarray,
                             prefetch_ids: np.ndarray):
         """Algorithm 1, invoked between batches (pipelined)."""
-        tr = get_tracer()
-        if tr.enabled:
-            t_pop = tr.clock.now()
-            ev0 = self.stats.evictions
+        self._populate([(trunk, bits, prefetch_ids)])
+
+    def _populate(self, items):
+        """Apply ``(trunk, bits, prefetch_ids)`` items under ONE
+        ``store.populate`` span whose args sum theirs: a flush applies
+        thousands of small items, and a span each would cost more than
+        most items do."""
+        st = self.stats
+        ev0 = st.evictions
+        with get_tracer().span("store", "populate", track="store") as span:
+            n_trunk = n_pf = 0
+            for trunk, bits, pf in items:
+                a, b = self._apply(trunk, bits, pf)
+                n_trunk += a
+                n_pf += b
+            span.set(trunk=n_trunk, pf_rows=n_pf, calls=len(items),
+                     evictions=st.evictions - ev0)
+
+    def _apply(self, trunk: np.ndarray, bits: np.ndarray,
+               prefetch_ids: np.ndarray) -> Tuple[int, int]:
+        """One set of model outputs; returns its trunk keys and
+        prefetched rows."""
+        st = self.stats
+        st.populate_calls += 1
         trunk = np.asarray(trunk, np.int64).ravel()
         bits = np.asarray(bits).ravel()
         m = min(trunk.size, bits.size)  # zip semantics: shorter side wins
@@ -857,16 +940,16 @@ class TieredEmbeddingStore:
             # priorities/residency).
             res = self._slot_map[trunk] >= 0
             self.recmg.load_embeddings(trunk[res], bits[res], [])
+            t1 = time.perf_counter()
             pf = self._new_prefetch_keys(pf_ids)
             if pf.size:
                 self._fetch_prefetch(pf)
                 self.recmg.set_priorities(pf, self.recmg.ev)
-            self.stats.model_s += time.perf_counter() - t0
-        if tr.enabled:
-            tr.add_span("store", "populate", t_pop,
-                        tr.clock.now() - t_pop, track="store", args={
-                            "trunk": int(trunk.size), "pf_rows": int(pf.size),
-                            "evictions": self.stats.evictions - ev0})
+            t2 = time.perf_counter()
+            st.rank_s += t1 - t0
+            st.prefetch_s += t2 - t1
+            st.model_s += t2 - t0
+        return trunk.size, pf.size
 
     def _new_prefetch_keys(self, pf_ids: np.ndarray) -> np.ndarray:
         """Non-resident prefetch targets, deduplicated, first-occurrence

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import time
+import functools
 from typing import Callable, Dict, Optional
 
 import jax
@@ -32,7 +32,7 @@ from repro.core.trace import Trace, TraceGenConfig, generate_trace
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.dlrm import init_dlrm_dense
 from repro.obs import MetricsRegistry
-from repro.obs.tracing import get_tracer
+from repro.obs.tracing import Steps, get_tracer
 
 
 # Rows per table when the published 72,704 do not fit the host: 856 x 8,192
@@ -76,6 +76,47 @@ def published_rows_per_table(cfg, mem_available: Optional[int]) -> int:
 
 
 @dataclasses.dataclass
+class ServeStats:
+    """Host seconds of the serve loop's own steps, always on (one
+    ``perf_counter`` reading per boundary), published under ``serve.*``.
+    On the synchronous path a batch is ``outputs_s + flush_s + lookup_s +
+    pool_s + forward_s`` of its steps, exactly: the previous batch's
+    outputs and flush, which this batch waits for, then its own lookup,
+    pooling and forward."""
+    outputs_s: float = 0.0  # staging the previous batch's model outputs
+    flush_s: float = 0.0  # flush_staged: applying them (store.populate)
+    lookup_s: float = 0.0  # store.lookup (its steps: TierStats)
+    pool_s: float = 0.0  # dispatch of pool_bags
+    forward_s: float = 0.0  # dense input, forward and its device sync
+    h2d_bytes: int = 0  # dense inputs sent to the forward
+
+    def as_dict(self) -> Dict[str, float]:
+        return dataclasses.asdict(self)
+
+    def publish(self, reg, lat, prefix: str = "serve"):
+        """The steps, and the count and summed seconds of the batches'
+        latencies ``lat``."""
+        reg.counter(f"{prefix}.batches").inc(len(lat))
+        reg.counter(f"{prefix}.batch_s").inc(float(sum(lat)))
+        for key, val in self.as_dict().items():
+            reg.counter(f"{prefix}.{key}").inc(val)
+        return reg
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def pool_bags(rows, queries: int, tables: int, bag: int):
+    """Sum-pool served rows, ``(n, D)`` in request order, into ``(queries,
+    tables, D)`` bags of ``bag`` rows, as one program (``jit_pool_bags``).
+    A partial batch (EDF pops under admission control can close one below
+    ``max_batch``) is zero-padded to the full shape first."""
+    pad = queries * tables * bag - rows.shape[0]
+    if pad:
+        rows = jnp.concatenate([rows, jnp.zeros((pad, rows.shape[1]),
+                                                rows.dtype)])
+    return rows.reshape(queries, tables, bag, rows.shape[1]).sum(axis=2)
+
+
+@dataclasses.dataclass
 class ServedBatch:
     """One served batch, handed to ``serve_trace``'s ``probe`` outside the
     timed window: what went in, what the store served, what came out."""
@@ -88,6 +129,10 @@ class ServedBatch:
     cfg: object  # the model config served (tables, width, pooling)
     params: dict  # dense-MLP parameters of the forward
     store: object  # the store that served the batch
+    # This batch's step seconds and counts (TierStats.steps() and
+    # ServeStats deltas since the previous batch's probe), the flush that
+    # ran before its lookup included; h2d_bytes sums store and forward.
+    steps: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
@@ -219,12 +264,16 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
             host, capacity, policy=pol, quantize=quantize,
             row_format=row_format, fetch_us_per_row=fetch_us_per_row,
             warmup_batch=per_batch)
-    fwd = jax.jit(lambda pr, d, e: _dense_forward(pr, cfg, d, e))
+
+    def dense_forward(pr, d, e):
+        return _dense_forward(pr, cfg, d, e)
+
+    fwd = jax.jit(dense_forward)  # runs as jit_dense_forward
 
     gid = trace.global_id
     rng = np.random.default_rng(1)
     chunk_state = {"ptr": 0}
-    compute = {"s": 0.0}
+    serve = ServeStats()
 
     from repro.core.model_runtime import OutputsRef
 
@@ -276,40 +325,45 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
             items.append((empty, empty, np.asarray(last_pf, np.int64)))
         return items
 
-    def forward_batch(emb):
-        """Pool + dense forward; returns ``(measured compute seconds,
-        logits, dense input)``.
-        Partial batches (EDF pops under admission control can close a
-        batch below ``max_batch``) are zero-padded to the full shape so
-        the jitted forward sees one shape — no per-size XLA recompiles
-        on the measured path."""
-        rows = batch_queries * T * P
-        if emb.shape[0] < rows:
-            emb = jnp.concatenate(
-                [emb, jnp.zeros((rows - emb.shape[0], emb.shape[1]),
-                                emb.dtype)])
-        emb = emb.reshape(batch_queries, T, P, cfg.emb_dim).sum(axis=2)
-        dense = jnp.asarray(
-            rng.normal(size=(batch_queries, cfg.dense_features))
-            .astype(np.float32))
-        t1 = time.perf_counter()
-        out = fwd(params, dense, emb)
-        jax.block_until_ready(out)
-        c = time.perf_counter() - t1
-        compute["s"] += c
-        return c, out, dense
+    def forward_batch(emb, clk: Steps):
+        """Pool + dense forward, as the steps ``pool`` and ``forward`` of
+        ``clk``; returns ``(forward seconds, logits, dense input)``."""
+        with clk.step(serve, "pool_s", "serve", "pool", track="serve"):
+            pooled = pool_bags(emb, batch_queries, T, P)
+        f0 = serve.forward_s
+        with clk.step(serve, "forward_s", "serve", "forward",
+                      track="serve"):
+            dense_np = rng.normal(
+                size=(batch_queries, cfg.dense_features)).astype(np.float32)
+            serve.h2d_bytes += dense_np.nbytes
+            dense = jnp.asarray(dense_np)
+            out = fwd(params, dense, pooled)
+            jax.block_until_ready(out)
+        return serve.forward_s - f0, out, dense
+
+    steps_seen = {}  # step totals at the previous probe
+
+    def step_totals():
+        d = store.stats.steps()
+        d.update(serve.as_dict(), h2d_bytes=d["h2d_bytes"]
+                 + serve.h2d_bytes)
+        return d
 
     def report(b, ids, emb, fwd_out):
         if probe is not None:
             _, logits, dense = fwd_out
+            now = step_totals()
+            steps = {k: v - steps_seen.get(k, 0) for k, v in now.items()}
+            steps_seen.update(now)
             probe(ServedBatch(b, ids, emb, dense, logits, host, cfg, params,
-                              store))
+                              store, steps))
 
-    # Warm the jitted dense forward off the measured path: its first-call
-    # XLA compile otherwise lands inside batch 0's latency window and
-    # dominates the p99 (~150ms against a ~5ms p50).  Shapes/dtypes match
-    # the real batches, so this is a pure compile-cache fill.
-    warm_pooled = jnp.zeros((batch_queries, T, cfg.emb_dim), jnp.float32)
+    # Warm the pooling and the dense forward off the measured path: their
+    # first-call XLA compiles otherwise land inside batch 0's latency
+    # window and dominate the p99 (~150ms against a ~5ms p50).  Shapes and
+    # dtypes match the real batches, so this is a pure compile-cache fill.
+    warm_rows = jnp.zeros((per_batch, cfg.emb_dim), host.dtype)
+    warm_pooled = pool_bags(warm_rows, batch_queries, T, P)
     warm_dense = jnp.zeros((batch_queries, cfg.dense_features), jnp.float32)
     jax.block_until_ready(fwd(params, warm_dense, warm_pooled))
 
@@ -355,7 +409,7 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
         served = {"n": 0}  # FIFO batches: batch b's ids follow b-1's
 
         def step(b, emb):
-            fwd_out = forward_batch(emb)
+            fwd_out = forward_batch(emb, Steps(get_tracer()))
             c = fwd_out[0]
             lo = served["n"]
             served["n"] += emb.shape[0]
@@ -387,36 +441,52 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
     else:
         lat = []
         _tr = get_tracer()
+
+        def stage_outputs(clk, b, ids, pre_hits):
+            """Stage batch ``b``'s model outputs and flush them: the steps
+            ``outputs`` and ``flush`` of the next batch, which waits for
+            them.  ``stage_model_outputs`` double-buffers, so the outputs
+            land at a batch boundary and never inside a lookup."""
+            with clk.step(serve, "outputs_s", "serve", "outputs",
+                          track="serve"):
+                for item in staged_for_batch(b):
+                    store.stage_model_outputs(*item)
+                if controller is not None:
+                    # Adaptation items stage after the model's: the fresh
+                    # re-ranks must win over stale ones at the next drain.
+                    for item in controller.on_batch(
+                            ids, store.stats.hits - pre_hits, b):
+                        store.stage_model_outputs(*item)
+            with clk.step(serve, "flush_s"):  # spans: store.populate
+                store.flush_staged()
+
+        # A batch is timed as its client waits for it: from the end of the
+        # previous batch's probe (its outputs and flush come first) to the
+        # end of this batch's forward, on the step timer's readings.
+        pending = None  # (b, ids, hits before its lookup) to stage
         for b in range(n_batches):
+            clk = Steps(_tr)
+            t0 = clk.t
+            if pending is not None:
+                stage_outputs(clk, *pending)
             if _tr.enabled:
                 _tr.set_batch(b)
             ids = gid[b * per_batch: (b + 1) * per_batch]
             pre_hits = store.stats.hits
-            t0 = time.perf_counter()
-            emb = store.lookup(ids)  # (per_batch, D)
-            fwd_out = forward_batch(emb)
-            lat.append(time.perf_counter() - t0)
+            with clk.step(serve, "lookup_s"):  # spans: store.lookup
+                emb = store.lookup(ids)  # (per_batch, D)
+            fwd_out = forward_batch(emb, clk)
+            lat.append(clk.t - t0)
             report(b, ids, emb, fwd_out)
-            # ``stage_model_outputs`` double-buffers: the outputs land at
-            # the next batch boundary without blocking an in-flight
-            # lookup; the flush runs in the inter-batch gap (outside the
-            # timed window) so measured batch latency matches the seed's
-            # accounting.
-            for item in staged_for_batch(b):
-                store.stage_model_outputs(*item)
-            if controller is not None:
-                # Adaptation items stage after the model's: the fresh
-                # re-ranks must win over stale ones at the next drain.
-                for item in controller.on_batch(
-                        ids, store.stats.hits - pre_hits, b):
-                    store.stage_model_outputs(*item)
-            store.flush_staged()
+            pending = (b, ids, pre_hits)
             if log and b % 10 == 0:
                 log(f"batch {b}: {lat[-1]*1e3:.1f} ms "
                     f"hit {store.stats.hit_rate:.3f}")
+        if pending is not None:
+            stage_outputs(Steps(_tr), *pending)
 
     st = store.stats.as_dict()
-    compute_ms = compute["s"] / max(n_batches, 1) * 1e3
+    compute_ms = serve.forward_s / max(n_batches, 1) * 1e3
     st.update(
         policy=policy,
         mean_batch_ms=float(np.mean(lat) * 1e3),
@@ -468,6 +538,7 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
     # ``--metrics-out``) sees a single flat counter space.
     reg = MetricsRegistry()
     store.publish_metrics(reg)
+    serve.publish(reg, lat)
     if rt is not None:
         rt.publish(reg)
     if controller is not None and hasattr(controller, "publish"):

@@ -20,6 +20,7 @@ from repro.obs.reconcile import (  # noqa: F401
 from repro.obs.tracing import (  # noqa: F401
     NullTracer,
     SpanTracer,
+    Steps,
     get_tracer,
     install_tracer,
     validate_chrome_trace,

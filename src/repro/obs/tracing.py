@@ -8,7 +8,12 @@ across runs) or a wall clock otherwise.  The tracer never imports
 
 Span taxonomy (category / name — see docs/architecture.md):
 
-* ``store`` — ``lookup`` (whole batch), ``gather``, ``admit`` per batch;
+* ``store`` — ``lookup`` (whole batch) over its steps ``partition``,
+  ``admit`` (itself over ``slow_read``, ``residency``, ``write``),
+  ``gather`` and ``sync``; ``populate``, one per flush of staged model
+  outputs (or per direct ``apply_model_outputs`` call);
+* ``serve`` — ``outputs`` (staging the model outputs), ``pool``,
+  ``forward`` per batch of the synchronous serve loop;
 * ``pf`` — ``channel`` (modeled background-channel occupancy per prefetch
   submit), ``populate``; instants ``timely`` / ``late`` / ``unused``;
 * ``rt`` — ``fetch`` / ``compute`` / ``stall`` lanes of the pipelined
@@ -19,19 +24,33 @@ Span taxonomy (category / name — see docs/architecture.md):
 
 Every event carries the current batch id (set once per batch via
 :meth:`SpanTracer.set_batch`) in ``args["batch"]`` so cross-layer events
-correlate.  Export is Chrome trace-event JSON (Perfetto-loadable):
+correlate; spans opened with :meth:`SpanTracer.span` also carry the
+enclosing span's ``cat.name`` in ``args["parent"]`` (``""`` at the top).
+On the wall clock those spans are also opened as
+``jax.profiler.TraceAnnotation`` of the same ``cat.name``, so a profiler
+session shows them on its host plane, on the device trace's clock.
+
+:class:`Steps` times consecutive steps of one code path with one
+``time.perf_counter`` reading per boundary: the reading that ends a step
+starts the next, feeds the step's seconds counter and, when the tracer is
+on, the span's ends, so the steps tile their parent exactly.
+
+Export is Chrome trace-event JSON (Perfetto-loadable):
 complete events (``ph: "X"``), instants (``ph: "i"``), plus ``ph: "M"``
 metadata naming each track.  A bounded ring buffer keeps the last N
 batches as a flight recorder for post-mortem dumps.
 
 Near-zero cost when disabled: the module-level tracer defaults to a
 :class:`NullTracer` whose ``enabled`` is ``False``; hot paths guard with
-``if tr.enabled:`` so the off cost is one attribute check per *batch*
-(never per row).
+``if tr.enabled:``, and :meth:`SpanTracer.span` returns a shared no-op
+span after that one check.  What stays on is the step timing: per step
+one ``perf_counter`` reading, one small step object and that check (never
+per row).
 """
 from __future__ import annotations
 
 import json
+import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
@@ -45,11 +64,98 @@ class _WallUs:
         return time.perf_counter() * 1e6
 
 
+class _NoSpan:
+    """The span of a disabled tracer: records nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def open(self, at: Optional[float] = None) -> None:
+        pass
+
+    def close(self, at: Optional[float] = None) -> None:
+        pass
+
+    def set(self, **args) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """A complete span over a ``with`` body (or from :meth:`open` to
+    :meth:`close`), appended when it ends, so the end timestamps on a
+    track stay monotone."""
+
+    __slots__ = ("tr", "cat", "name", "track", "args", "ts", "_ann")
+
+    def __init__(self, tr: "SpanTracer", cat: str, name: str, track: str,
+                 args: Dict[str, Any]) -> None:
+        self.tr, self.cat, self.name, self.track = tr, cat, name, track
+        self.args = args
+        self.ts = 0.0
+        self._ann = None
+
+    def set(self, **args) -> None:
+        """Add args to the span (counts known only inside its body)."""
+        self.args.update(args)
+
+    def _reading(self, at: Optional[float]) -> float:
+        # ``at`` is a ``time.perf_counter()`` reading: the wall clock's
+        # own, in seconds; a modeled clock keeps its own time.
+        if at is not None and self.tr.wall:
+            return at * 1e6
+        return self.tr.clock.now()
+
+    def open(self, at: Optional[float] = None) -> None:
+        """Start the span, at the ``time.perf_counter()`` reading ``at``
+        where given (wall clock only)."""
+        tr = self.tr
+        label = f"{self.cat}.{self.name}"
+        stack = tr._stack()
+        self.args["parent"] = stack[-1] if stack else ""
+        stack.append(label)
+        self.ts = self._reading(at)
+        if tr.wall:
+            from jax.profiler import TraceAnnotation
+
+            self._ann = TraceAnnotation(label)
+            self._ann.__enter__()
+
+    def close(self, at: Optional[float] = None) -> None:
+        """End the span (at ``at``, as for :meth:`open`) and record it."""
+        tr = self.tr
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        tr._stack().pop()
+        end = self._reading(at)
+        tr.add_span(self.cat, self.name, self.ts, end - self.ts, self.track,
+                    self.args)
+
+    def __enter__(self) -> "_Span":
+        self.open()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.close()
+        return False
+
+
 class NullTracer:
     """Disabled tracer: every record method is a no-op.  ``enabled`` is
     False so instrumented code can skip even argument construction."""
 
     enabled = False
+
+    def span(self, cat: str, name: str, track: str = "main",
+             **args) -> _NoSpan:
+        return _NO_SPAN
 
     def set_batch(self, batch_id: int) -> None:  # pragma: no cover - trivial
         pass
@@ -76,6 +182,12 @@ class SpanTracer:
     def __init__(self, clock: Optional[Any] = None,
                  ring_batches: int = 64) -> None:
         self.clock = clock if clock is not None else _WallUs()
+        # Wall-clock spans also go to the JAX profiler's host plane; a
+        # modeled (virtual) timeline has no place there.
+        self.wall = isinstance(self.clock, _WallUs)
+        # Labels of each thread's open spans, innermost last: a prefetch
+        # worker's spans must not become the parents of the main thread's.
+        self._tls = threading.local()
         self.events: List[Dict[str, Any]] = []
         self.ring_batches = max(1, int(ring_batches))
         # The in-progress batch occupies one ring slot, so the deque of
@@ -118,6 +230,24 @@ class SpanTracer:
             "ts": float(ts), "dur": max(0.0, float(dur)),
             "pid": 0, "tid": self._tid(track), "args": a,
         })
+
+    def span(self, cat: str, name: str, track: str = "main", **args):
+        """Context manager recording a complete span over its ``with``
+        body, with ``args`` (extend them with ``.set(**kw)`` inside), the
+        enclosing span of the same thread in ``args["parent"]`` and the
+        batch id.  Reads ``enabled`` once, on entry: a disabled tracer
+        returns a shared no-op span."""
+        if not self.enabled:
+            return _NO_SPAN
+        return _Span(self, cat, name, track, args)
+
+    def _stack(self) -> List[str]:
+        """This thread's open span labels."""
+        try:
+            return self._tls.open
+        except AttributeError:
+            self._tls.open = []
+            return self._tls.open
 
     def add_instant(self, cat: str, name: str, ts: Optional[float] = None,
                     track: str = "main",
@@ -182,6 +312,66 @@ class SpanTracer:
         trace and the counter snapshot (e.g. sum of ``hit_ids`` over
         ``store.lookup`` spans must equal ``store.fast.hits``)."""
         return sum(e["args"].get(arg, 0) for e in self.spans(cat, name))
+
+
+# ---------------- step timing ----------------
+
+class Steps:
+    """Times consecutive steps of one code path with one
+    ``time.perf_counter`` reading per boundary.
+
+    ``step(...)`` opens where the previous step (or the constructor's
+    reading) ended and reads the clock once when it closes, so adjacent
+    steps share their boundary reading and their seconds tile the time
+    from the first reading to the last exactly.  A step adds its seconds
+    to ``into.<field>``, always; when ``tracer`` is on it also records the
+    span ``cat.name`` over it, on the same readings where the tracer runs
+    on the wall clock.  ``group(...)`` is a span over steps: it takes no
+    reading of its own and ends where its last step ended."""
+
+    __slots__ = ("tr", "t")
+
+    def __init__(self, tracer: Any) -> None:
+        self.tr = tracer
+        self.t = time.perf_counter()
+
+    def step(self, into: Any, field: str, cat: Optional[str] = None,
+             name: str = "", track: str = "main", **args) -> "_Step":
+        """A step that adds its seconds to ``into.<field>``; ``cat=None``
+        records no span (the step is covered by spans of another layer)."""
+        span = _NO_SPAN if cat is None else self.tr.span(cat, name, track,
+                                                         **args)
+        return _Step(self, into, field, span)
+
+    def group(self, cat: str, name: str, track: str = "main",
+              **args) -> "_Step":
+        """A span over the steps of its body, from the reading its first
+        step starts at to the one its last step ends at."""
+        return _Step(self, None, "", self.tr.span(cat, name, track, **args))
+
+
+class _Step:
+    """A step of :class:`Steps` (``into`` set) or a group of steps."""
+
+    __slots__ = ("steps", "into", "field", "span", "t0")
+
+    def __init__(self, steps: Steps, into: Any, field: str, span) -> None:
+        self.steps, self.into, self.field = steps, into, field
+        self.span = span
+
+    def __enter__(self):
+        self.t0 = self.steps.t
+        self.span.open(self.t0)
+        return self.span
+
+    def __exit__(self, *exc) -> bool:
+        steps = self.steps
+        if self.into is not None:
+            steps.t = time.perf_counter()
+            setattr(self.into, self.field,
+                    getattr(self.into, self.field) + (steps.t - self.t0))
+        self.span.close(steps.t)
+        return False
 
 
 # ---------------- module-level tracer ----------------
