@@ -250,13 +250,15 @@ class TierStats:
     rank_s: float = 0.0  # RecMG priorities of the accessed chunks
     prefetch_s: float = 0.0  # RecMG prefetch admission and priorities
     populate_calls: int = 0  # apply_model_outputs calls
+    rank_passes: int = 0  # engine passes that ranked trunks
     write_rows: int = 0  # rows written into the fast tier (before padding)
     overflow_rows: int = 0  # rows served through the overflow select
     h2d_bytes: int = 0  # bytes of the host arrays lookups and writes send
 
     SECONDS = ("partition_s", "slow_read_s", "residency_s", "write_s",
                "gather_dispatch_s", "sync_s", "rank_s", "prefetch_s")
-    COUNTS = ("populate_calls", "write_rows", "overflow_rows", "h2d_bytes")
+    COUNTS = ("populate_calls", "rank_passes", "write_rows", "overflow_rows",
+              "h2d_bytes")
 
     @property
     def hit_rate(self):
@@ -902,54 +904,74 @@ class TieredEmbeddingStore:
         self._populate([(trunk, bits, prefetch_ids)])
 
     def _populate(self, items):
-        """Apply ``(trunk, bits, prefetch_ids)`` items under ONE
-        ``store.populate`` span whose args sum theirs: a flush applies
-        thousands of small items, and a span each would cost more than
-        most items do."""
+        """Apply ``(trunk, bits, prefetch_ids)`` items (1-D arrays) in
+        order, under ONE ``store.populate`` span whose args sum theirs.
+
+        Under recmg the trunks of each maximal run of items that carry no
+        prefetch ids are ranked in one engine pass: the ranking buffer is
+        unbounded, so nothing evicts; the epoch and the slot map hold still
+        until a prefetch is admitted; and ``set_many`` gives every
+        occurrence its own seq, the last winning, as a per-item loop does.
+        An item with prefetch ids ends the run its trunk joins, so a later
+        trunk is masked against the slot map after that admission."""
         st = self.stats
-        ev0 = st.evictions
+        ev0, passes0 = st.evictions, st.rank_passes
+        recmg = self.policy == "recmg"
         with get_tracer().span("store", "populate", track="store") as span:
             n_trunk = n_pf = 0
-            for trunk, bits, pf in items:
-                a, b = self._apply(trunk, bits, pf)
-                n_trunk += a
-                n_pf += b
+            keys, bits = [], []
+            for trunk, b, pf in items:
+                m = len(trunk)
+                if m != len(b):  # zip semantics: the shorter side wins
+                    m = min(m, len(b))
+                    trunk, b = trunk[:m], b[:m]
+                n_trunk += m
+                if recmg and m:
+                    keys.append(trunk)
+                    bits.append(b)
+                if len(pf):
+                    if keys:
+                        self._rank(keys, bits)
+                        keys, bits = [], []
+                    n_pf += self._prefetch(pf)
+            if keys:
+                self._rank(keys, bits)
+            st.populate_calls += len(items)
             span.set(trunk=n_trunk, pf_rows=n_pf, calls=len(items),
+                     rank_passes=st.rank_passes - passes0,
                      evictions=st.evictions - ev0)
 
-    def _apply(self, trunk: np.ndarray, bits: np.ndarray,
-               prefetch_ids: np.ndarray) -> Tuple[int, int]:
-        """One set of model outputs; returns its trunk keys and
-        prefetched rows."""
+    def _rank(self, keys: List[np.ndarray], bits: List[np.ndarray]):
+        """One engine pass over a run's concatenated trunks and bits."""
         st = self.stats
-        st.populate_calls += 1
-        trunk = np.asarray(trunk, np.int64).ravel()
-        bits = np.asarray(bits).ravel()
-        m = min(trunk.size, bits.size)  # zip semantics: shorter side wins
-        trunk, bits = trunk[:m], bits[:m]
-        pf_ids = np.asarray(prefetch_ids, np.int64).ravel()
-        if self.policy != "recmg":
-            # LRU+PF mode: only prefetch insertion applies.
-            pf = self._new_prefetch_keys(pf_ids)
-            if pf.size:
-                self._fetch_prefetch(pf)
-        else:
-            t0 = time.perf_counter()
-            # Only rank RESIDENT keys (pipelined outputs can reference
-            # vectors already evicted; ranking them would desync
-            # priorities/residency).
-            res = self._slot_map[trunk] >= 0
-            self.recmg.load_embeddings(trunk[res], bits[res], [])
-            t1 = time.perf_counter()
-            pf = self._new_prefetch_keys(pf_ids)
-            if pf.size:
-                self._fetch_prefetch(pf)
-                self.recmg.set_priorities(pf, self.recmg.ev)
-            t2 = time.perf_counter()
-            st.rank_s += t1 - t0
-            st.prefetch_s += t2 - t1
-            st.model_s += t2 - t0
-        return trunk.size, pf.size
+        t0 = time.perf_counter()
+        trunk = np.concatenate(keys).astype(np.int64, copy=False)
+        b = np.concatenate(bits)
+        # Only rank RESIDENT keys (pipelined outputs can reference
+        # vectors already evicted; ranking them would desync
+        # priorities/residency).
+        res = self._slot_map[trunk] >= 0
+        self.recmg.load_embeddings(trunk[res], b[res], [])
+        dt = time.perf_counter() - t0
+        st.rank_passes += 1
+        st.rank_s += dt
+        st.model_s += dt
+
+    def _prefetch(self, prefetch_ids) -> int:
+        """Admit an item's non-resident prefetch targets (and, under recmg,
+        rank them); returns the rows prefetched."""
+        st = self.stats
+        t0 = time.perf_counter()
+        pf = self._new_prefetch_keys(
+            np.asarray(prefetch_ids, np.int64).ravel())
+        if pf.size:
+            self._fetch_prefetch(pf)
+        if self.policy == "recmg":
+            self.recmg.set_priorities(pf, self.recmg.ev)
+            dt = time.perf_counter() - t0
+            st.prefetch_s += dt
+            st.model_s += dt
+        return pf.size
 
     def _new_prefetch_keys(self, pf_ids: np.ndarray) -> np.ndarray:
         """Non-resident prefetch targets, deduplicated, first-occurrence

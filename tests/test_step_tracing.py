@@ -95,12 +95,12 @@ def _counts(store):
 @pytest.mark.parametrize("capacity,counts", [
     # 3 unique misses fit: 3 rows written through a 16-row bucket (slots
     # 64 B + rows 512 B), one (2, 16) int32 gather operand (128 B).
-    (16, {"populate_calls": 0, "write_rows": 3, "overflow_rows": 0,
-          "h2d_bytes": 64 + 512 + 128}),
+    (16, {"populate_calls": 0, "rank_passes": 0, "write_rows": 3,
+          "overflow_rows": 0, "h2d_bytes": 64 + 512 + 128}),
     # 6 unique misses into 4 slots (LRU): the first 2 overflow and are
     # served by the select, with its mask (16 B) and host rows (512 B).
-    (4, {"populate_calls": 0, "write_rows": 4, "overflow_rows": 2,
-         "h2d_bytes": 64 + 512 + 128 + 16 + 512}),
+    (4, {"populate_calls": 0, "rank_passes": 0, "write_rows": 4,
+         "overflow_rows": 2, "h2d_bytes": 64 + 512 + 128 + 16 + 512}),
 ])
 def test_step_counts_are_exact(capacity, counts):
     host = np.arange(64 * 8, dtype=np.float32).reshape(64, 8)
@@ -120,6 +120,39 @@ def test_step_counts_are_exact(capacity, counts):
     assert after["populate_calls"] == 3
     assert after["write_rows"] == counts["write_rows"] + 3
     assert after["h2d_bytes"] == counts["h2d_bytes"] + 3 * (64 + 512)
+
+
+@pytest.mark.parametrize("policy,passes", [("recmg", 1), ("lru", 0)])
+def test_flush_ranks_a_run_in_one_pass(policy, passes):
+    """N trunk-only items and one prefetch item: N + 1 populate calls and,
+    under RecMG, one rank pass; the flush's span carries both deltas."""
+    rng = np.random.default_rng(0)
+    host = rng.normal(size=(512, 8)).astype(np.float32)
+    store = TieredEmbeddingStore(host, 64, policy=policy)
+    store.lookup(np.arange(100))
+    before = _counts(store)
+    n = 40
+    tr = install_tracer(SpanTracer())
+    try:
+        for _ in range(n):
+            store.stage_model_outputs(rng.integers(0, 100, 15),
+                                      rng.integers(0, 2, 15),
+                                      np.empty(0, np.int64))
+        store.stage_model_outputs(rng.integers(0, 100, 15),
+                                  rng.integers(0, 2, 15),
+                                  np.arange(200, 204))
+        store.flush_staged()
+    finally:
+        install_tracer(None)
+    after = _counts(store)
+    calls = after["populate_calls"] - before["populate_calls"]
+    ranked = after["rank_passes"] - before["rank_passes"]
+    assert (calls, ranked) == (n + 1, passes)
+    (span,) = tr.spans("store", "populate")
+    assert span["args"]["calls"] == calls
+    assert span["args"]["rank_passes"] == ranked
+    assert span["args"]["pf_rows"] == 4
+    assert (store.stats.model_s > 0) == (policy == "recmg")
 
 
 @pytest.mark.parametrize("policy", ["recmg", "lru"])
@@ -155,6 +188,8 @@ def test_traced_batch_spans(served, policy):
     pops = tr.spans("store", "populate")
     calls = res["metrics"]["counters"]["store.steps.populate_calls"]
     assert sum(e["args"]["calls"] for e in pops) == calls
+    assert sum(e["args"]["rank_passes"] for e in pops) \
+        == res["metrics"]["counters"]["store.steps.rank_passes"]
     if policy == "recmg":
         assert len(pops) == n and calls > n
 

@@ -130,3 +130,83 @@ def test_staged_outputs_apply_at_next_boundary():
     st.lookup(np.array([5, 6]))
     assert st.stats.prefetch_hits == 2  # staged prefetch landed first
     assert st.stats.hits == 2
+
+
+EMPTY = np.empty(0, np.int64)
+
+
+def _chunks(rng, keys, n, size=15):
+    """``n`` trunk-only items of ``size`` ids drawn from ``keys`` (so keys
+    repeat across chunks), with random caching bits."""
+    return [(rng.choice(keys, size),
+             (rng.random(size) < 0.5).astype(np.int64), EMPTY)
+            for _ in range(n)]
+
+
+def _flush_items(case, rng, resident, n_rows):
+    """The model outputs one flush applies; returns them and the key the
+    flush must leave non-resident (or None)."""
+    non_res = np.setdiff1d(np.arange(n_rows), resident)
+    if case == "repeated_chunks":
+        return _chunks(rng, resident[:24], 80), None
+    if case == "non_resident":
+        return _chunks(rng, np.arange(n_rows), 80), None
+    if case == "prefetch_evicts":
+        # Every resident key ranked cache-friendly but ``v``, ranked
+        # evict-next: the mid-flush prefetch of one key evicts ``v``,
+        # and the trunks after it name ``v`` again.
+        v = int(resident[5])
+        items = [(c, (c != v).astype(np.int64), EMPTY)
+                 for c in np.array_split(resident, len(resident) // 15)]
+        items.append((resident[6:21], np.ones(15, np.int64),
+                      non_res[:1]))
+        return items + [(np.r_[v, c[0]], np.r_[1, c[1]], EMPTY)
+                        for c in _chunks(rng, resident, 40)], v
+    if case == "ragged":
+        return [(t, b[:len(t) - 4] if i % 2 else np.r_[b, b], EMPTY)
+                for i, (t, b, _) in enumerate(
+                    _chunks(rng, np.arange(n_rows), 80))], None
+    assert case == "lru"
+    items = _chunks(rng, np.arange(n_rows), 80)
+    items[40] = (items[40][0], items[40][1], non_res[:6])
+    return items, None
+
+
+@pytest.mark.parametrize("case,policy", [
+    ("repeated_chunks", "recmg"), ("non_resident", "recmg"),
+    ("prefetch_evicts", "recmg"), ("ragged", "recmg"), ("lru", "lru"),
+])
+def test_flush_matches_per_item_reference(case, policy):
+    """A flush of many staged items (each run of prefetch-free items
+    ranked in one engine pass) leaves the store where the per-item
+    reference leaves it: equal counters and residency after the flush and
+    at every batch of the eviction-heavy lookups that follow."""
+    rng = np.random.default_rng(7)
+    host = rng.normal(size=(500, 8)).astype(np.float32)
+    cap = 64
+    ids = _trace(rng, 500, 4800)
+    new = TieredEmbeddingStore(host, cap, policy=policy)
+    ref = ReferenceTieredStore(host, cap, policy=policy)
+    for b in range(10):  # fill the fast tier
+        new.lookup(ids[b * 48: (b + 1) * 48])
+        ref.lookup(ids[b * 48: (b + 1) * 48])
+    resident = np.array(sorted(ref.slot_of), np.int64)
+    assert len(resident) == cap and set(new.slot_of) == set(ref.slot_of)
+    items, gone = _flush_items(case, np.random.default_rng(8), resident,
+                               host.shape[0])
+    for item in items:
+        new.stage_model_outputs(*item)
+        ref.apply_model_outputs(*item)
+    new.flush_staged()
+    assert new.stats.populate_calls == len(items)
+    if gone is not None:
+        assert gone not in new.slot_of and gone not in ref.slot_of
+    for b in range(10, len(ids) // 48):
+        assert set(new.slot_of) == set(ref.slot_of), b
+        for c in COUNTERS:
+            assert getattr(new.stats, c) == getattr(ref.stats, c), (b, c)
+        chunk = ids[b * 48: (b + 1) * 48]
+        np.testing.assert_allclose(np.asarray(new.lookup(chunk)),
+                                   host[chunk], rtol=1e-6)
+        ref.lookup(chunk)
+    new.check_invariants()
