@@ -17,7 +17,8 @@ metrics; ``--trace 1`` reports its per-layer metrics, with ``busy_s``,
   labelled by the host span that covers it;
 * profiled: the next ``traced_batches``, profiled with the tracer off;
   the device metrics, ``busy_s``, ``window_s`` and the top device ops;
-* untraced: the rest; the metrics read from the store's counters.
+* untraced: the rest; the metrics read from the store's counters and
+  from the sums of the served batches' steps.
 
 JAX's persistent compilation cache is kept in ``.jax_cache/`` inside the
 checkout, whatever the environment says.
@@ -50,7 +51,7 @@ for _p in (str(ROOT / "src"), str(ROOT)):
 
 import numpy as np  # noqa: E402
 
-from bench import check, costs, profile, spec  # noqa: E402
+from bench import check, profile, spec  # noqa: E402
 from bench.traffic import World, generate, load_mix  # noqa: E402
 from bench.window import (COUNTERS, Window, WindowClosed,  # noqa: E402
                           counters)
@@ -134,7 +135,8 @@ def learned_model(cell, world, rows, capacity, cache_dir: Path):
         return LearnedRecMGModel(lcfg, saved["mcfg"], saved["pcfg"],
                                  saved["cparams"], saved["pparams"],
                                  saved["cand"], capacity, None), "cached"
-    tab, row = generate(world, cell.config["multi_hot"], n_q, PROFILE_STREAM)
+    tab, row = generate(world, spec.pooling(cell.config), n_q,
+                        PROFILE_STREAM)
     model = LearnedRecMGModel.train_from_trace(
         _trace(tab, row, rows), capacity, lcfg)
     import jax
@@ -185,7 +187,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     t0 = time.perf_counter()
     world = World(load_mix(cell.traffic, bench_dir / "traffic"), rows)
     n_q = b * (int(p["warmup_batches"]) + int(p["window_batches"]))
-    tab, row = generate(world, config["multi_hot"], n_q, SERVED_STREAM,
+    tab, row = generate(world, spec.pooling(config), n_q, SERVED_STREAM,
                         query_offset=b * int(p.get("profile_batches", 0)))
     trace = _trace(tab, row, rows)
     del tab, row
@@ -210,7 +212,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     tracer = prof_dir = None
     labelled = LABELLED_BATCHES
     traced_batches = int(p.get("traced_batches", 3))
-    prof = SimpleNamespace(phase="off", ids=[], at=0, stats=None)
+    prof = SimpleNamespace(phase="off", ids=[], at=0, stats=None, steps={})
     if traced:
         from repro.obs.tracing import SpanTracer, install_tracer
 
@@ -256,6 +258,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
             jax.profiler.stop_trace()
             prof.phase, prof.at = "untraced", w.batches
             prof.stats = counters(w.store)
+            prof.steps = dict(w.steps)
 
     def on_batch(w, rec):
         if prof.phase == "labelled" and w.batches >= labelled:
@@ -299,7 +302,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     store = window.store
     pallas = None
     if dev.platform == "tpu":
-        text = store.gather_program_text(b * cfg.n_tables * cfg.multi_hot)
+        text = store.gather_program_text(b * spec.ids_per_query(config))
         pallas = bool(store.use_kernel and "tpu_custom_call" in text)
         log(f"gather: {'pallas kernel' if pallas else 'xla'}")
     problems = []
@@ -342,16 +345,17 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
         n_untraced = window.batches - prof.at
         rest = (window.delta() if prof.stats is None else
                 {k: window.stats_end[k] - prof.stats[k] for k in COUNTERS})
+        steps = {k: v - prof.steps.get(k, 0) for k, v in window.steps.items()}
         busy = profile.busy_s(pr)
         ctx = SimpleNamespace(
             cell=cell, config=config, policy=config["policy"], peak=peak,
-            costs=costs, profile=pr, busy_s=busy,
+            costs=spec.cost_module(cell), profile=pr, busy_s=busy,
             profile_seconds=lambda pred: profile.op_seconds(pr, pred),
             program_seconds=lambda pred: profile.run_seconds(pr, pred),
             window=SimpleNamespace(batches=n_untraced,
                                    queries=n_untraced * b,
                                    seconds=float(sum(parts["untraced"])),
-                                   delta=rest),
+                                   delta=rest, steps=steps),
             profiled=SimpleNamespace(
                 batches=len(prof.ids), queries=len(prof.ids) * b,
                 unique_rows=[np.unique(np.asarray(i)).size

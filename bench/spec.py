@@ -7,7 +7,11 @@ entries only, never an edit:
 * ``<bench>/configs/<config>.json`` -- the deployment as served: sizes,
   the fast tier's share and the policy that manages it (``"policy"``:
   ``recmg`` or ``lru``), with the plain reference module it names
-  (``"reference"``) beside it;
+  (``"reference"``) beside it, and the module of its operation and byte
+  counts if it names one (``"costs"``; else ``bench/costs.py``).  Every key
+  that names a field of the program's ``ModelConfig`` reaches the program;
+  ``rows_per_table`` and ``multi_hot`` are one int for every table or a
+  list of one per table;
 * ``<bench>/traffic/<traffic>.json`` -- the mix's parameters;
 * ``<bench>/cells/<cell>.json`` -- how the cell serves: queries per batch, warm-up, trace room, profiling and checking sizes;
 * ``<bench>/metrics/<metric>.py`` -- one reader per per-layer metric, a
@@ -18,7 +22,7 @@ from __future__ import annotations
 
 import importlib.util
 import json
-from dataclasses import dataclass
+from dataclasses import dataclass, fields
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
@@ -83,6 +87,19 @@ def reference_module(cell: Cell):
                        f"bench_reference_{ref}")
 
 
+def cost_module(cell: Cell):
+    """The operation and byte counts the cell's configuration names
+    (``"costs"``, a module in ``<bench>/configs/``), by default
+    ``bench/costs.py``; the per-layer readers get it as ``ctx.costs``."""
+    name = cell.config.get("costs")
+    if name is None:
+        from bench import costs
+
+        return costs
+    return load_module(cell.bench_dir / "configs" / f"{name}.py",
+                       f"bench_costs_{name}")
+
+
 def metric_reader(name: str, bench_dir: Path = BENCH_DIR
                   ) -> Callable[[object], Optional[float]]:
     """``read(ctx)`` of the per-layer metric ``name``."""
@@ -101,29 +118,42 @@ def peaks(device_kind: str, bench_dir: Path = BENCH_DIR) -> Dict[str, float]:
 
 
 def model_config(config: dict):
-    """The program's ``ModelConfig`` for a ``dlrm`` configuration file."""
+    """The program's ``ModelConfig`` for a ``dlrm`` configuration file:
+    every key of the file that names a ``ModelConfig`` field, lists as
+    tuples, with ``family="dlrm"`` and ``rows_per_table`` the largest
+    table's rows.  Keys that name no field are the harness's own."""
     from repro.configs.base import ModelConfig
 
-    rows = config["rows_per_table"]
-    return ModelConfig(
-        name=config["name"], family="dlrm",
-        n_tables=int(config["n_tables"]),
-        rows_per_table=int(max(rows) if isinstance(rows, list) else rows),
-        emb_dim=int(config["emb_dim"]), multi_hot=int(config["multi_hot"]),
-        dense_features=int(config["dense_features"]),
-        bottom_mlp=tuple(config["bottom_mlp"]),
-        top_mlp=tuple(config["top_mlp"]),
-        param_dtype=config["param_dtype"],
-        compute_dtype=config["compute_dtype"],
-        source=config["source"])
+    names = {f.name for f in fields(ModelConfig)}
+    kw = {k: tuple(v) if isinstance(v, list) else v
+          for k, v in config.items() if k in names}
+    kw.update(family="dlrm", rows_per_table=max(table_rows(config)))
+    return ModelConfig(**kw)
+
+
+def _per_table(config: dict, key: str):
+    """``config[key]`` as a list of ``n_tables`` ints: one int for every
+    table, or a list of exactly one per table."""
+    v, n = config[key], int(config["n_tables"])
+    if isinstance(v, list):
+        if len(v) != n:
+            raise ValueError(f"{config['name']}: {len(v)} values of {key} "
+                             f"for {n} tables")
+        return [int(x) for x in v]
+    return [int(v)] * n
 
 
 def table_rows(config: dict):
     """Rows of each table as served, a list of ``n_tables`` ints."""
-    rows = config["rows_per_table"]
-    if isinstance(rows, list):
-        if len(rows) != int(config["n_tables"]):
-            raise ValueError(f"{config['name']}: {len(rows)} row counts for "
-                             f"{config['n_tables']} tables")
-        return [int(r) for r in rows]
-    return [int(rows)] * int(config["n_tables"])
+    return _per_table(config, "rows_per_table")
+
+
+def pooling(config: dict):
+    """Ids each table pools a query (``multi_hot``), a list of ``n_tables``
+    ints."""
+    return _per_table(config, "multi_hot")
+
+
+def ids_per_query(config: dict) -> int:
+    """Ids one query looks up: the sum of every table's pooling."""
+    return sum(pooling(config))

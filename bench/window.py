@@ -14,6 +14,8 @@ forward.
   after ``seconds`` closes the window by raising :class:`WindowClosed`.
 * Kept batches: a reservoir of ``keep`` window batches, drawn from the
   seed, held by reference (device arrays) for the check after the window.
+* Steps: ``steps``, the running sum over the window's batches of each
+  batch's ``ServedBatch.steps`` (the program's step seconds and counts).
 """
 from __future__ import annotations
 
@@ -61,6 +63,7 @@ class Window:
     kept: List[Kept] = field(default_factory=list)
     stats_start: dict = field(default_factory=dict)
     stats_end: dict = field(default_factory=dict)
+    steps: dict = field(default_factory=dict)
     queries_per_batch: int = 0
     compiles_at_start: int = 0
 
@@ -84,6 +87,9 @@ class Window:
             return
         self.latencies.append(t - self._t_prev)
         self._t_prev = t
+        steps = self.steps
+        for k, v in rec.steps.items():
+            steps[k] = steps.get(k, 0) + v
         self._reservoir(rec)
         if self.on_batch is not None:
             self.on_batch(self, rec)

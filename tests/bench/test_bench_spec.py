@@ -51,3 +51,69 @@ def test_each_config_and_traffic_pair_once(path):
     pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
     assert len(pairs) == len(set(pairs)), pairs
     assert {c["name"] for c in bench["configs"]} == {c for c, _ in pairs}
+
+
+def _parent_model_config(config: dict):
+    """``spec.model_config`` as it was before it passed the file's
+    ``ModelConfig`` keys through: the oracle for the three files."""
+    from repro.configs.base import ModelConfig
+
+    rows = config["rows_per_table"]
+    return ModelConfig(
+        name=config["name"], family="dlrm",
+        n_tables=int(config["n_tables"]),
+        rows_per_table=int(max(rows) if isinstance(rows, list) else rows),
+        emb_dim=int(config["emb_dim"]), multi_hot=int(config["multi_hot"]),
+        dense_features=int(config["dense_features"]),
+        bottom_mlp=tuple(config["bottom_mlp"]),
+        top_mlp=tuple(config["top_mlp"]),
+        param_dtype=config["param_dtype"],
+        compute_dtype=config["compute_dtype"],
+        source=config["source"])
+
+
+def _config(path):
+    return json.loads(Path(path).read_text())
+
+
+@pytest.mark.parametrize("name", ["dlrm-recmg", "dlrm-recmg.lru",
+                                  "dlrm-mlperf-criteo1tb"])
+def test_model_config_is_the_one_built_before(name):
+    cfg = _config(spec.BENCH_DIR / "configs" / f"{name}.json")
+    assert spec.model_config(cfg) == _parent_model_config(cfg)
+
+
+def test_per_table_pooling_reaches_the_program():
+    cfg = _config(FIXTURES / "configs" / "dlrm-dcnv2-pooling.json")
+    mc = spec.model_config(cfg)
+    assert mc.multi_hot == tuple(cfg["multi_hot"])
+    assert mc.rows_per_table == max(cfg["rows_per_table"]) == 4096
+    assert mc.family == "dlrm" and mc.n_tables == 26
+    assert mc.bottom_mlp == (512, 256, 128)
+    assert spec.pooling(cfg) == cfg["multi_hot"]
+    assert spec.ids_per_query(cfg) == 214
+    assert not hasattr(mc, "published")
+
+
+def test_pooling_one_int_or_one_per_table():
+    cfg = {"name": "c", "n_tables": 3, "multi_hot": 4}
+    assert spec.pooling(cfg) == [4, 4, 4] and spec.ids_per_query(cfg) == 12
+    cfg["multi_hot"] = [1, 2, 3]
+    assert spec.pooling(cfg) == [1, 2, 3] and spec.ids_per_query(cfg) == 6
+    for wrong in ([1, 2], [1, 2, 3, 4]):
+        with pytest.raises(ValueError, match="multi_hot"):
+            spec.pooling(dict(cfg, multi_hot=wrong))
+    with pytest.raises(ValueError, match="rows_per_table"):
+        spec.table_rows(dict(cfg, rows_per_table=[5, 6]))
+
+
+def test_cost_module_is_the_one_the_configuration_names(fixture_root):
+    from bench import costs
+
+    b = fixture_root / "bench"
+    default = spec.load_cell("tiny-dlrm.fixture_mix.lru", fixture_root, b)
+    assert spec.cost_module(default) is costs
+    own = spec.load_cell("tiny-dlrm.own_costs.fixture_mix", fixture_root, b)
+    mod = spec.cost_module(own)
+    assert mod.__file__ == str(b / "configs" / "tiny_costs.py")
+    assert mod.flops_per_query(own.config) == mod.FLOPS_PER_QUERY

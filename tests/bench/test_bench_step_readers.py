@@ -1,6 +1,7 @@
 """The per-layer readers of named device programs, on a synthetic profile:
 they sum their programs' executions in the window per profiled batch, and
-read nothing where no program carries their names."""
+read nothing where no program carries their names.  The readers of the
+served batches' steps average them over the untraced batches."""
 import importlib.util
 from types import SimpleNamespace
 
@@ -58,3 +59,26 @@ def test_reader_is_silent_without_its_programs(name):
     assert _reader(name)(ctx) is None
     assert _reader(name)(SimpleNamespace(profile=None)) is None
     assert _reader(name)(_ctx(PROGRAMS, batches=0)) is None
+
+
+def _steps_ctx(steps, batches):
+    return SimpleNamespace(window=SimpleNamespace(batches=batches,
+                                                  steps=steps))
+
+
+STEPS = {"h2d_bytes": 3 * 72_500_000, "overflow_rows": 66, "lookup_s": 0.2}
+
+
+@pytest.mark.parametrize("name,value", [
+    ("serve.h2d_mb_per_batch", 72.5),
+    ("store.overflow_rows_per_batch", 22.0),
+])
+def test_step_reader_averages_the_untraced_batches(name, value):
+    assert _reader(name)(_steps_ctx(STEPS, 3)) == pytest.approx(value)
+
+
+@pytest.mark.parametrize("name", ["serve.h2d_mb_per_batch",
+                                  "store.overflow_rows_per_batch"])
+def test_step_reader_is_silent_without_its_steps(name):
+    assert _reader(name)(_steps_ctx(STEPS, 0)) is None
+    assert _reader(name)(_steps_ctx({"lookup_s": 0.2}, 3)) is None
