@@ -11,7 +11,7 @@ dtypes, sharding variant) that the perf loop iterates on.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Tuple
+from typing import Tuple, Union
 
 # ---------------------------------------------------------------------------
 # Model config
@@ -69,16 +69,27 @@ class ModelConfig:
     n_tables: int = 0
     rows_per_table: int = 0
     emb_dim: int = 0
-    multi_hot: int = 0  # pooling factor per table
+    # Ids pooled per table: one for every table, or one per table.
+    multi_hot: Union[int, Tuple[int, ...]] = 0
     dense_features: int = 0
     bottom_mlp: Tuple[int, ...] = ()
     top_mlp: Tuple[int, ...] = ()
+    # Feature interaction: "dot" (DLRM's pairwise dots) or "dcnv2" (DCN V2's
+    # low-rank cross network over the concatenated features).
+    interaction: str = "dot"
+    cross_layers: int = 0  # dcnv2: cross layers
+    cross_rank: int = 0  # dcnv2: rank of each layer's V and W
 
     # Dtypes / runtime defaults (overridable via RunConfig).
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
 
     source: str = ""  # provenance note: [source; verified-tier]
+
+    def __post_init__(self):
+        if self.interaction not in ("dot", "dcnv2"):
+            raise ValueError(f"interaction {self.interaction!r}: 'dot' or "
+                             "'dcnv2'")
 
     # ---------------- derived helpers ----------------
     @property
@@ -102,6 +113,17 @@ class ModelConfig:
     @property
     def expert_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def pooling(self) -> Tuple[int, ...]:
+        """Ids each table pools a query, one per table."""
+        if isinstance(self.multi_hot, int):
+            return (self.multi_hot,) * self.n_tables
+        return tuple(self.multi_hot)
+
+    @property
+    def ids_per_query(self) -> int:
+        return sum(self.pooling)
 
     @property
     def is_attention_free(self) -> bool:

@@ -18,7 +18,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import functools
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +30,7 @@ from repro.core.serving import MultiTableTieredStore
 from repro.core.tiered import TieredEmbeddingStore
 from repro.core.trace import Trace, TraceGenConfig, generate_trace
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models.dlrm import init_dlrm_dense
+from repro.models.dlrm import forward_pooled, init_dlrm_dense
 from repro.obs import MetricsRegistry
 from repro.obs.tracing import Steps, get_tracer
 
@@ -89,6 +89,7 @@ class ServeStats:
     pool_s: float = 0.0  # dispatch of pool_bags
     forward_s: float = 0.0  # dense input, forward and its device sync
     h2d_bytes: int = 0  # dense inputs sent to the forward
+    pool_rows: int = 0  # rows pool_bags summed into bags
 
     def as_dict(self) -> Dict[str, float]:
         return dataclasses.asdict(self)
@@ -103,17 +104,26 @@ class ServeStats:
         return reg
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def pool_bags(rows, queries: int, tables: int, bag: int):
-    """Sum-pool served rows, ``(n, D)`` in request order, into ``(queries,
-    tables, D)`` bags of ``bag`` rows, as one program (``jit_pool_bags``).
-    A partial batch (EDF pops under admission control can close one below
-    ``max_batch``) is zero-padded to the full shape first."""
-    pad = queries * tables * bag - rows.shape[0]
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def pool_bags(rows, queries: int, pooling: Tuple[int, ...]):
+    """Sum-pool served rows, ``(n, D)`` in request order (each query's
+    ``pooling[0]`` rows of table 0, then ``pooling[1]`` of table 1, ...),
+    into ``(queries, tables, D)`` bags, as one program (``jit_pool_bags``).
+    One bag size for every table pools by one reshape; uneven sizes sum
+    each table's segment of the query.  A partial batch (EDF pops under
+    admission control can close one below ``max_batch``) is zero-padded to
+    the full shape first."""
+    pad = queries * sum(pooling) - rows.shape[0]
     if pad:
         rows = jnp.concatenate([rows, jnp.zeros((pad, rows.shape[1]),
                                                 rows.dtype)])
-    return rows.reshape(queries, tables, bag, rows.shape[1]).sum(axis=2)
+    tables, d = len(pooling), rows.shape[1]
+    if len(set(pooling)) == 1:
+        return rows.reshape(queries, tables, pooling[0], d).sum(axis=2)
+    q = rows.reshape(queries, sum(pooling), d)
+    ends = np.cumsum(pooling)
+    return jnp.stack([q[:, e - p:e].sum(axis=1)
+                      for p, e in zip(pooling, ends)], axis=1)
 
 
 @dataclasses.dataclass
@@ -121,8 +131,10 @@ class ServedBatch:
     """One served batch, handed to ``serve_trace``'s ``probe`` outside the
     timed window: what went in, what the store served, what came out."""
     index: int
-    ids: np.ndarray  # (B*T*P,) global row ids in request order
-    rows: jax.Array  # (B*T*P, D) the rows the store served
+    # (B * sum(P_t),) global row ids in request order: each query's P_0
+    # ids of table 0, then P_1 of table 1, ...
+    ids: np.ndarray
+    rows: jax.Array  # (B * sum(P_t), D) the rows the store served
     dense: jax.Array  # (B, dense_features) dense input
     logits: jax.Array  # (B,) served logits
     host: np.ndarray  # the slow tier the rows came from
@@ -217,14 +229,15 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
     0.  ``probe``, if given, is called with a :class:`ServedBatch` after
     every batch, outside the timed window (it needs FIFO batches, so not
     with ``overload``)."""
-    T, P = cfg.n_tables, cfg.multi_hot
-    per_batch = batch_queries * T * P
+    pooling = cfg.pooling
+    qp = cfg.ids_per_query  # ids per query = one request
+    segments, longest_bag = batch_queries * len(pooling), max(pooling)
+    per_batch = batch_queries * qp
     n_batches = len(trace.global_id) // per_batch
     if n_batches == 0:
         raise ValueError(
             f"the trace holds {len(trace.global_id)} accesses, fewer than "
-            f"one batch of {batch_queries} queries x {T} tables x {P} ids "
-            f"= {per_batch}")
+            f"one batch of {batch_queries} queries x {qp} ids = {per_batch}")
     if probe is not None and overload:
         raise ValueError("probe needs FIFO batches; admission control "
                          "(overload) reorders them")
@@ -328,8 +341,11 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
     def forward_batch(emb, clk: Steps):
         """Pool + dense forward, as the steps ``pool`` and ``forward`` of
         ``clk``; returns ``(forward seconds, logits, dense input)``."""
-        with clk.step(serve, "pool_s", "serve", "pool", track="serve"):
-            pooled = pool_bags(emb, batch_queries, T, P)
+        n = int(emb.shape[0])
+        with clk.step(serve, "pool_s", "serve", "pool", track="serve",
+                      segments=segments, longest_bag=longest_bag, rows=n):
+            pooled = pool_bags(emb, batch_queries, pooling)
+        serve.pool_rows += n
         f0 = serve.forward_s
         with clk.step(serve, "forward_s", "serve", "forward",
                       track="serve"):
@@ -363,7 +379,7 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
     # window and dominate the p99 (~150ms against a ~5ms p50).  Shapes and
     # dtypes match the real batches, so this is a pure compile-cache fill.
     warm_rows = jnp.zeros((per_batch, cfg.emb_dim), host.dtype)
-    warm_pooled = pool_bags(warm_rows, batch_queries, T, P)
+    warm_pooled = pool_bags(warm_rows, batch_queries, pooling)
     warm_dense = jnp.zeros((batch_queries, cfg.dense_features), jnp.float32)
     jax.block_until_ready(fwd(params, warm_dense, warm_pooled))
 
@@ -419,7 +435,6 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
                     f"stall {rt.telemetry.stall_ms:.1f} ms")
             return c, staged_for_batch(b)
 
-        qp = T * P  # ids per query = one request
         n_queries = n_batches * batch_queries
         if adm_cfg is not None:
             mix = np.asarray(priority_mix if priority_mix is not None
@@ -547,21 +562,9 @@ def serve_trace(cfg, params, trace: Trace, capacity: int, policy: str,
     return st
 
 
-def _dense_forward(params, cfg, dense, pooled):
-    """DLRM forward given already-pooled embeddings (B, T, D)."""
-    from repro.models.dlrm import _mlp
-
-    ct = jnp.dtype(cfg.compute_dtype)
-    bot = _mlp(params["bottom"], dense.astype(ct))
-    z = jnp.concatenate([bot[:, None, :], pooled.astype(ct)], axis=1)
-    zz = jnp.einsum("bfd,bgd->bfg", z, z, preferred_element_type=jnp.float32)
-    f = z.shape[1]
-    # NumPy indices are constants of the program; jnp.triu_indices is
-    # computed in it, and at 857 features takes ~50 s to compile for a v5e.
-    iu, ju = np.triu_indices(f, k=1)
-    inter = zz[:, iu, ju]
-    top_in = jnp.concatenate([bot.astype(jnp.float32), inter], axis=1)
-    return _mlp(params["top"], top_in.astype(ct))[:, 0]
+# The forward every served batch runs, as ``jit_dense_forward``: logits from
+# the pooled embeddings (B, T, D), with the interaction ``cfg`` names.
+_dense_forward = forward_pooled
 
 
 def main(argv=None, probe: Optional[Callable[[ServedBatch], None]] = None):
@@ -585,7 +588,7 @@ def main(argv=None, probe: Optional[Callable[[ServedBatch], None]] = None):
                          f"(to {CUT_ROWS_PER_TABLE}) are cut")
     ap.add_argument("--batches", type=int, default=40,
                     help="batches to serve; the trace holds exactly "
-                         "batches x batch-queries x tables x pooling ids")
+                         "batches x batch-queries x the ids of a query")
     ap.add_argument("--batch-queries", type=int, default=32)
     ap.add_argument("--capacity-frac", type=float, default=0.2)
     ap.add_argument("--train-epochs", type=int, default=3)
@@ -694,8 +697,7 @@ def main(argv=None, probe: Optional[Callable[[ServedBatch], None]] = None):
             cfg = dataclasses.replace(cfg, rows_per_table=rows)
     else:
         cfg = cfg.reduced()
-    accesses = args.batches * args.batch_queries * cfg.n_tables \
-        * cfg.multi_hot
+    accesses = args.batches * args.batch_queries * cfg.ids_per_query
     print(f"cut: {args.batches} batches x {args.batch_queries} queries "
           f"({accesses} accesses)")
     # Serving reads embedding rows from the host tier: only the dense

@@ -38,7 +38,9 @@ Identities (see docs/architecture.md for the derivations):
 
 The trace cross-check (:func:`check_trace_vs_metrics`) closes the loop
 between the two observability surfaces: per-batch span args summed over
-the trace must equal the counter snapshot exactly.
+the trace must equal the counter snapshot exactly (the ``store.lookup``
+args the store counters, the ``serve.pool`` spans' ``rows`` the
+``serve.pool_rows`` counter).
 """
 from __future__ import annotations
 
@@ -259,15 +261,23 @@ def check_trace_vs_metrics(trace: Dict[str, Any],
                            store_prefix: str = "store") -> List[str]:
     """Spans must reconcile *exactly* with the counter snapshot: per-batch
     ``store.lookup`` span args summed over the trace equal the store
-    counters.  ``trace`` is a Chrome trace object (``{"traceEvents":
-    [...]}``)."""
+    counters, and the ``serve.pool`` spans' ``rows`` ``serve.pool_rows``.
+    ``trace`` is a Chrome trace object (``{"traceEvents": [...]}``)."""
     evs = trace.get("traceEvents", [])
+    p: List[str] = []
+    if "serve.pool_rows" in flat and any(
+            e.get("ph") == "X" and e.get("cat") == "serve"
+            and e.get("name") == "pool" for e in evs):
+        got = _span_sums(evs, "serve", "pool", "rows")
+        want = _get(flat, "serve.pool_rows")
+        if abs(got - want) > _EPS:
+            p.append(f"trace: sum(rows over serve.pool spans)={got:g} "
+                     f"!= serve.pool_rows={want:g}")
     lookup_spans = [e for e in evs if e.get("ph") == "X"
                     and e.get("cat") == "store"
                     and e.get("name") == "lookup"]
     if not lookup_spans or not _has_any(flat, store_prefix):
-        return []  # nothing traced on this surface — vacuous
-    p: List[str] = []
+        return p  # no store traced on this surface — vacuous
     pairs = [
         ("ids", f"{store_prefix}.lookups"),
         ("hit_ids", f"{store_prefix}.fast.hits"),
