@@ -38,9 +38,9 @@ residency (checked per batch in O(1)).
 
 The gather path is **device-resident end-to-end**: one jitted
 ``buf[idx][inv]`` fused gather per batch (both index vectors padded to
-power-of-two shape buckets), overflow rows folded in through a jitted
-``where``-select over staged host rows instead of a device->host->device
-bounce, and no intermediate ``block_until_ready`` between the miss-path
+power-of-two shape buckets), overflow rows shipped alone from the host
+and folded in on the device instead of a device->host->device bounce,
+and no intermediate ``block_until_ready`` between the miss-path
 scatter and the gather — fetch and gather pipeline inside one device sync
 (``fetch_s`` therefore measures host-side admit + dispatch; execution time
 lands in ``gather_s``).  ``warmup(batch_hint)`` (or the ``warmup_batch``
@@ -108,23 +108,37 @@ def _bucket(n: int) -> int:
 # by every store instance (per-instance lambdas would recompile the same
 # shape buckets once per table/shard).  ``inv`` folds the unique->request
 # expansion into the same fused program, so the result never leaves the
-# device; the ``_OV`` variants where-select staged host rows for overflow
-# (working set larger than the buffer) without a host round-trip.
+# device; the ``_OV`` variants fold in the host rows of overflow (working
+# set larger than the buffer) without a host round-trip.
 # ---------------------------------------------------------------------------
 
 # ``iv`` packs both index vectors — row 0 the unique slots, row 1 the
 # unique->request inverse — into one operand, so each gather costs a
 # single host->device transfer.
 _JIT_GATHER = jax.jit(lambda buf, iv: buf[iv[0]][iv[1]])
+
+
+# The ``_OV`` programs take the overflow rows as ``hr`` ``(ob, D)``; the
+# request positions that read them point past the bucket in ``iv[1]``
+# (``bucket + k`` for ``hr[k]``), into the gathered rows followed by
+# ``hr``.  Rows are copied, never computed, so the fold is exact.
 _JIT_GATHER_OV = jax.jit(
-    lambda buf, iv, ov, hr: jnp.where(ov[:, None], hr, buf[iv[0]])[iv[1]])
+    lambda buf, iv, hr: jnp.concatenate([buf[iv[0]], hr])[iv[1]])
 _JIT_GATHER_Q = jax.jit(
     lambda buf, sc, iv:
     (buf[iv[0]].astype(jnp.float32) * sc[iv[0]][:, None])[iv[1]])
 _JIT_GATHER_Q_OV = jax.jit(
-    lambda buf, sc, iv, ov, hr:
-    jnp.where(ov[:, None], hr,
-              buf[iv[0]].astype(jnp.float32) * sc[iv[0]][:, None])[iv[1]])
+    lambda buf, sc, iv, hr:
+    jnp.concatenate([buf[iv[0]].astype(jnp.float32) * sc[iv[0]][:, None],
+                     hr])[iv[1]])
+
+
+def _small_overflow(bsz: int) -> int:
+    """Rows of the overflow operand a gather bucket of ``bsz`` ships while
+    its overflow rows fit (1/64 of the bucket, at least 16); past that it
+    ships the whole bucket."""
+    return max(16, bsz // 64)
+
 
 # The writes into the fast tier run as programs named ``store_write*``
 # (``jit_store_write``, ``jit_store_write_quant``, ...), so a profile finds
@@ -167,19 +181,22 @@ def kernel_gather_ok(backend: str, d: int, fast_dtype) -> bool:
 
 
 def _n_unique(iv):
-    """Unique-row count of a packed ``(2, bucket)`` gather operand: the
-    unique->request inverse (row 1) is onto ``range(u)`` and its padding
-    is 0, so ``u = max(inverse) + 1``."""
-    return jnp.max(iv[1]) + 1
+    """Unique rows the expansion of a packed ``(2, bucket)`` gather operand
+    reads: its entries of row 1 below the bucket are onto ``range(u)``
+    less the overflow rows (which point past the bucket) and its padding
+    is 0, so the rows up to the largest such entry are all it reads."""
+    inv = iv[1]
+    return jnp.max(jnp.where(inv < inv.shape[0], inv, -1)) + 1
 
 
 def _kernel_gathers(quantized: bool = False, interpret: bool = False):
     """Pallas row-gather variants, built lazily (TPU backend, or any
     backend under ``interpret=True``).  The fp32 pair copies only the
-    ``u`` unique rows of the bucket; ``quantized=True`` returns the
-    dequantizing pair (interpret mode only, see :func:`kernel_gather_ok`).
-    Both fold the overflow where-select and the unique->request expansion
-    into the same program."""
+    unique rows of the bucket that the expansion reads; ``quantized=True``
+    returns the dequantizing pair (interpret mode only, see
+    :func:`kernel_gather_ok`).
+    Both fold the overflow rows and the unique->request expansion into
+    the same program."""
     key = ("gq" if quantized else "g", interpret)
     if key not in _KERNEL_JITS:
         from repro.kernels import embedding_gather as eg
@@ -189,20 +206,19 @@ def _kernel_gathers(quantized: bool = False, interpret: bool = False):
                 return eg.gather_rows_dequant(buf, sc, iv[0],
                                               interpret=_i)[iv[1]]
 
-            def gov(buf, sc, iv, ov, hr, _i=interpret):
-                return jnp.where(
-                    ov[:, None], hr,
-                    eg.gather_rows_dequant(buf, sc, iv[0],
-                                           interpret=_i))[iv[1]]
+            def gov(buf, sc, iv, hr, _i=interpret):
+                return jnp.concatenate(
+                    [eg.gather_rows_dequant(buf, sc, iv[0], interpret=_i),
+                     hr])[iv[1]]
         else:
             def g(buf, iv, _i=interpret):
                 return eg.gather_rows(buf, iv[0], _n_unique(iv),
                                       interpret=_i)[iv[1]]
 
-            def gov(buf, iv, ov, hr, _i=interpret):
-                return jnp.where(ov[:, None], hr,
-                                 eg.gather_rows(buf, iv[0], _n_unique(iv),
-                                                interpret=_i))[iv[1]]
+            def gov(buf, iv, hr, _i=interpret):
+                return jnp.concatenate(
+                    [eg.gather_rows(buf, iv[0], _n_unique(iv), interpret=_i),
+                     hr])[iv[1]]
         _KERNEL_JITS[key] = (jax.jit(g), jax.jit(gov))
     return _KERNEL_JITS[key]
 
@@ -252,13 +268,14 @@ class TierStats:
     populate_calls: int = 0  # apply_model_outputs calls
     rank_passes: int = 0  # engine passes that ranked trunks
     write_rows: int = 0  # rows written into the fast tier (before padding)
-    overflow_rows: int = 0  # rows served through the overflow select
+    overflow_rows: int = 0  # rows served through the overflow fold
+    overflow_full: int = 0  # gathers whose overflow took the whole bucket
     h2d_bytes: int = 0  # bytes of the host arrays lookups and writes send
 
     SECONDS = ("partition_s", "slow_read_s", "residency_s", "write_s",
                "gather_dispatch_s", "sync_s", "rank_s", "prefetch_s")
     COUNTS = ("populate_calls", "rank_passes", "write_rows", "overflow_rows",
-              "h2d_bytes")
+              "overflow_full", "h2d_bytes")
 
     @property
     def hit_rate(self):
@@ -496,7 +513,8 @@ class TieredEmbeddingStore:
 
     def warmup(self, batch_hint: int):
         """Eagerly compile the jitted scatter/gather for every power-of-two
-        shape bucket a batch of up to ``batch_hint`` ids can hit, so XLA
+        shape bucket a batch of up to ``batch_hint`` ids can hit (the
+        overflow gather at both of its operand sizes), so XLA
         compiles land at construction instead of inside measured batches
         (they showed up as ~600ms p99 spikes against a ~10ms p50).  The
         jitted functions are module-level: across tables/shards only the
@@ -506,14 +524,14 @@ class TieredEmbeddingStore:
         b = 16
         while b <= bi:
             iv = jnp.zeros((2, b), jnp.int32)
-            ov = jnp.zeros(b, bool)
-            hr = jnp.zeros((b, d), self._out_np_dtype)
             gather_args = (
                 (self.buffer, self.scales) if self.quantize
                 else (self.buffer,)
             )
             self._gather_inv(*gather_args, iv)
-            self._gather_ov(*gather_args, iv, ov, hr)
+            for ob in {_small_overflow(b), b}:
+                hr = jnp.zeros((ob, d), self._out_np_dtype)
+                self._gather_ov(*gather_args, iv, hr)
             # Scatter warm-up must not clobber buffer contents: rewrite
             # slot 0 with its own current row (a no-op write).
             slots = jnp.zeros(b, jnp.int32)
@@ -804,8 +822,8 @@ class TieredEmbeddingStore:
         m_ids = ids.size
         t_gather = steps.t
         with steps.step(st, "gather_dispatch_s", "store", "gather",
-                        track="store", uniq=int(u)):
-            out = self._gather(uniq, slots_u, inv, m_ids)
+                        track="store", uniq=int(u)) as gather_span:
+            out = self._gather(uniq, slots_u, inv, m_ids, gather_span)
         # Span args carry the batch's exact counter deltas — the trace
         # <-> metrics reconciliation sums these over all lookup spans.
         lookup_span.set(ids=m_ids, uniq=int(u), hit_ids=n_hit,
@@ -815,17 +833,16 @@ class TieredEmbeddingStore:
         return out, m_ids, t_gather
 
     def _gather(self, uniq: np.ndarray, slots_u: np.ndarray,
-                inv: np.ndarray, m_ids: int):
+                inv: np.ndarray, m_ids: int, span):
         """Dispatch the batch's device gather; returns the padded rows.
 
-        One fused jitted pass does the slot gather, the overflow
-        where-select, and the unique->request expansion, so the result
-        never bounces through the host.  The two index vectors are packed
-        into one (2, bucket) operand — a single transfer — and share ONE
-        power-of-two bucket (u <= M always): independent buckets would
-        give O(log^2) compiled shape combos, and per-table sub-batch sizes
-        vary enough to hit them all at runtime.  Buckets are warmed
-        eagerly by :meth:`warmup`."""
+        One fused jitted pass does the slot gather, the overflow fold, and
+        the unique->request expansion, so the result never bounces through
+        the host.  The two index vectors are packed into one (2, bucket)
+        operand — a single transfer — and share ONE power-of-two bucket
+        (u <= M always): independent buckets would give O(log^2) compiled
+        shape combos, and per-table sub-batch sizes vary enough to hit them
+        all at runtime.  Buckets are warmed eagerly by :meth:`warmup`."""
         gather_args = (
             (self.buffer, self.scales) if self.quantize else (self.buffer,)
         )
@@ -833,24 +850,30 @@ class TieredEmbeddingStore:
         bsz = _bucket(m_ids)
         iv = np.zeros((2, bsz), np.int32)
         np.maximum(slots_u, 0, out=iv[0, :u], casting="unsafe")
-        iv[1, :m_ids] = inv
-        overflow = slots_u < 0
-        n_over = int(np.count_nonzero(overflow))
+        over = np.flatnonzero(slots_u < 0)
+        n_over = over.size
         if not n_over:
+            iv[1, :m_ids] = inv
             self.stats.h2d_bytes += iv.nbytes
             return self._gather_inv(*gather_args, jnp.asarray(iv))
         # A batch whose unique working set exceeds the buffer can evict
-        # rows admitted earlier in the same batch; stage those rows from
-        # the host tier into the padded gather input and fold them in with
-        # a jitted where-select (counted as on-demand already).
-        ov = np.zeros(bsz, bool)
-        ov[:u] = overflow
-        hrows = np.zeros((bsz, self.host.shape[1]), self._out_np_dtype)
-        hrows[:u][overflow] = self.host[uniq[overflow]]
+        # rows admitted earlier in the same batch; ship those rows alone
+        # from the host tier, in the smallest operand of the bucket that
+        # holds them, and point the requests for them past the bucket, at
+        # their row of that operand (counted as on-demand already).
+        small = _small_overflow(bsz)
+        ob = small if n_over <= small else bsz
+        remap = np.arange(u, dtype=np.int32)
+        remap[over] = bsz + np.arange(n_over, dtype=np.int32)
+        np.take(remap, inv, out=iv[1, :m_ids])
+        hrows = np.zeros((ob, self.host.shape[1]), self._out_np_dtype)
+        hrows[:n_over] = self.host[uniq[over]]
         self.stats.overflow_rows += n_over
-        self.stats.h2d_bytes += iv.nbytes + ov.nbytes + hrows.nbytes
+        self.stats.overflow_full += int(ob > small)
+        self.stats.h2d_bytes += iv.nbytes + hrows.nbytes
+        span.set(overflow_rows=n_over, overflow_bucket=ob)
         return self._gather_ov(*gather_args, jnp.asarray(iv),
-                               jnp.asarray(ov), jnp.asarray(hrows))
+                               jnp.asarray(hrows))
 
     def _write_rows(self, slots: np.ndarray, rows: np.ndarray):
         if not len(slots):

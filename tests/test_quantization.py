@@ -111,7 +111,7 @@ def test_kernel_gather_matches_jit_gather(host):
     for k in ("batches", "lookups", "hits", "misses", "on_demand_rows",
               "evictions"):
         assert st_jit.stats.as_dict()[k] == st_ker.stats.as_dict()[k]
-    # Overflow path (working set > capacity): where-select fold included.
+    # Overflow path (working set > capacity): overflow fold included.
     big = np.arange(60)
     np.testing.assert_array_equal(np.asarray(st_jit.lookup(big)),
                                   np.asarray(st_ker.lookup(big)))

@@ -96,11 +96,13 @@ def _counts(store):
     # 3 unique misses fit: 3 rows written through a 16-row bucket (slots
     # 64 B + rows 512 B), one (2, 16) int32 gather operand (128 B).
     (16, {"populate_calls": 0, "rank_passes": 0, "write_rows": 3,
-          "overflow_rows": 0, "h2d_bytes": 64 + 512 + 128}),
+          "overflow_rows": 0, "overflow_full": 0,
+          "h2d_bytes": 64 + 512 + 128}),
     # 6 unique misses into 4 slots (LRU): the first 2 overflow and are
-    # served by the select, with its mask (16 B) and host rows (512 B).
+    # folded in from their host rows in a 16-row operand (512 B).
     (4, {"populate_calls": 0, "rank_passes": 0, "write_rows": 4,
-         "overflow_rows": 2, "h2d_bytes": 64 + 512 + 128 + 16 + 512}),
+         "overflow_rows": 2, "overflow_full": 0,
+         "h2d_bytes": 64 + 512 + 128 + 512}),
 ])
 def test_step_counts_are_exact(capacity, counts):
     host = np.arange(64 * 8, dtype=np.float32).reshape(64, 8)

@@ -21,6 +21,8 @@ TABLES, POOLING, QUERIES = 856, 20, 16
 # A 16-query batch at published widths is 16 * 856 * 20 = 273,920 ids; the
 # store pads it to this power-of-two bucket.
 FULL_BUCKET = tiered._bucket(QUERIES * TABLES * POOLING)
+# The overflow operand a batch of that bucket ships when few rows overflow.
+SMALL_OVERFLOW = tiered._small_overflow(FULL_BUCKET)
 BUFFER_ROWS = 200_000
 
 
@@ -57,6 +59,7 @@ def _compile(fn, *args):
 
 def test_bucket_is_full_width():
     assert FULL_BUCKET == 524_288
+    assert SMALL_OVERFLOW == 8_192
 
 
 def test_gather_rows_full_bucket(spec):
@@ -69,13 +72,13 @@ def test_gather_rows_full_bucket(spec):
 @pytest.mark.parametrize("overflow", [False, True])
 def test_store_kernel_lookup_full_bucket(spec, overflow):
     """The store's compiled lookup program (kernel gather + unique->request
-    expansion, with and without the overflow fold) at the full bucket."""
+    expansion, with and without the overflow fold of a small overflow
+    operand) at the full bucket."""
     g, gov = tiered._kernel_gathers(quantized=False)
     args = [spec((BUFFER_ROWS, D), jnp.float32),
             spec((2, FULL_BUCKET), jnp.int32)]
     if overflow:
-        args += [spec((FULL_BUCKET,), jnp.bool_),
-                 spec((FULL_BUCKET, D), jnp.float32)]
+        args.append(spec((SMALL_OVERFLOW, D), jnp.float32))
     text = (gov if overflow else g).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
 
@@ -106,7 +109,7 @@ def test_quantized_store_xla_rule_full_bucket(spec, row_format):
     texts = [
         tiered._JIT_GATHER_Q.lower(buf, sc, iv).compile().as_text(),
         tiered._JIT_GATHER_Q_OV.lower(
-            buf, sc, iv, spec((FULL_BUCKET,), jnp.bool_),
-            spec((FULL_BUCKET, D), jnp.float32)).compile().as_text(),
+            buf, sc, iv,
+            spec((SMALL_OVERFLOW, D), jnp.float32)).compile().as_text(),
     ]
     assert not any("tpu_custom_call" in t for t in texts)
